@@ -23,16 +23,6 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                   ".jax_cache"))
-try:
-    import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ["JAX_COMPILATION_CACHE_DIR"])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:
-    pass
 
 
 def run_cell(n_nodes: int, n_pre: int, n_measured: int):
@@ -67,6 +57,8 @@ def run_cell(n_nodes: int, n_pre: int, n_measured: int):
 
 
 def main() -> int:
+    from kubernetes_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cells = os.environ.get("MATRIX_CELLS",
                            "100:0,100:1000,1000:0,1000:1000")
     n_measured = int(os.environ.get("MATRIX_MEASURED", "1000"))

@@ -1,25 +1,7 @@
 """kubernetes_tpu: a TPU-native cluster-scheduling framework.
 
 See README.md for the architecture and SURVEY.md for the reference analysis.
+Entry points place JAX's persistent compilation cache through
+`kubernetes_tpu.utils.compile_cache.enable_compile_cache`; importing the
+package sets nothing.
 """
-
-import os as _os
-
-
-def _enable_persistent_compile_cache() -> None:
-    """Opt-out persistent XLA compilation cache: the placement kernels cost
-    seconds to compile per shape bucket; caching them on disk makes fresh
-    processes (benches, tests, sidecars) start warm. Set via environment so
-    importing the package costs nothing — jax reads these when (if) it is
-    imported. Disable with KUBERNETES_TPU_NO_COMPILE_CACHE=1 or override by
-    setting your own cache dir."""
-    if _os.environ.get("KUBERNETES_TPU_NO_COMPILE_CACHE"):
-        return
-    _os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        _os.path.expanduser("~/.cache/kubernetes_tpu/xla"))
-    _os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                           "0.5")
-
-
-_enable_persistent_compile_cache()

@@ -107,6 +107,8 @@ def main(argv=None) -> int:
         print(f"error: unknown component {comp!r}; have "
               f"{sorted(COMPONENTS)} + version", file=sys.stderr)
         return 1
+    from kubernetes_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     return fn(rest)
 
 

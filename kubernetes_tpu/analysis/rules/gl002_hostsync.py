@@ -3,7 +3,7 @@
 Every host touch of a device value (`np.asarray(dev)`, `.item()`,
 `float(dev)`, `int(dev)`, `bool(dev)`, `.block_until_ready()`) blocks the
 caller until the device drains — on the pipelined drain that forfeits the
-whole overlap (the device wait PROFILE_r07 worked to hide), and on the
+whole overlap (the device wait the pipeline exists to hide), and on the
 extender warm path it's a per-request stall. The design budget is ONE
 blessed sync per wave (`engine/waves.py` place_waves) plus the harvest's
 fetch; everything else must either stay on device or carry a

@@ -1203,8 +1203,7 @@ class Scheduler:
         # chunk sizing: enough waves for the overlap to hide device time,
         # few enough that per-wave fixed costs (refresh, encode reuse,
         # group assume) stay amortized — a pre-loaded 30k queue drains as
-        # two double-buffered waves (measured optimum on the CPU box;
-        # PROFILE_r07.md)
+        # two double-buffered waves (measured optimum on the CPU box)
         ready = self.queue.ready_count()
         chunk = max_batch or max(self.pipeline_chunk, -(-ready // 2))
         pipe = self.pipeline(chunk=chunk, overlap=overlap)

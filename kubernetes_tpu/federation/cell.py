@@ -253,9 +253,10 @@ def run_cell_process(cfg: Dict, out_q, ctrl_q) -> None:
     final accounting the federation audits need: every (pod, node)
     placement from STORE truth plus the service counters."""
     import os
-    # before any kubernetes_tpu import: the engine pulls in jax, and a
-    # CI cell must never grab an accelerator the parent owns
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # before any kubernetes_tpu import: the engine pulls in jax
+    os.environ["JAX_PLATFORMS"] = cfg["platform"]
+    import jax
+
     from kubernetes_tpu.models.hollow import hollow_nodes
     from kubernetes_tpu.parallel.multiproc import audit_duplicate_binds
 
@@ -274,7 +275,8 @@ def run_cell_process(cfg: Dict, out_q, ctrl_q) -> None:
         spill_after_attempts=int(cfg.get("spill_after_attempts", 2)))
     try:
         agent.start()
-        out_q.put({"cell": name, "port": agent.port, "ok": True})
+        out_q.put({"cell": name, "port": agent.port, "ok": True,
+                   "platform": jax.devices()[0].platform})
         while True:
             try:
                 msg = ctrl_q.get(timeout=0.5)
@@ -287,6 +289,7 @@ def run_cell_process(cfg: Dict, out_q, ctrl_q) -> None:
         bound = {p.key(): p.node_name for p in pods if p.node_name}
         out_q.put({
             "cell": name, "ok": True, "final": True,
+            "platform": jax.devices()[0].platform,
             "bound": bound,
             "pending": sum(1 for p in pods if not p.node_name),
             "duplicate_binds": audit_duplicate_binds(agent.api),

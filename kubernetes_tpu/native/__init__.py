@@ -6,11 +6,17 @@ with g++), with pure-Python/numpy fallbacks so every path works without a
 toolchain. `lib()` returns the loaded library or None; the public
 functions below pick the fast path automatically and are bit-identical
 either way (tests/test_native.py asserts both sides).
+
+The built library is named after the SHA-256 of the committed source
+(`native/libhostops-<sha256[:16]>.so`, the same name build/Makefile
+gives it), so a library left on disk from other source is never loaded:
+an edited hostops.cc builds a new file.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,7 +28,16 @@ import numpy as np
 _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_ROOT, "native", "hostops.cc")
-_SO = os.path.join(_ROOT, "native", "libhostops.so")
+
+
+def so_path() -> Optional[str]:
+    """The library path keyed on the source's content; None without it."""
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except FileNotFoundError:
+        return None
+    return os.path.join(_ROOT, "native", f"libhostops-{digest}.so")
 
 _lock = lockcheck.make_lock("native._lock")
 _lib: Optional[ctypes.CDLL] = None
@@ -43,8 +58,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def lib() -> Optional[ctypes.CDLL]:
-    """The loaded library, building it once with g++ if absent. None when
-    no prebuilt .so exists and the build fails (no toolchain)."""
+    """The loaded library, building it once with g++ when no library of
+    the current source exists. None when the build fails (no toolchain)."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
@@ -52,19 +67,25 @@ def lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) and os.path.exists(_SRC):
+        so = so_path()
+        if so is None:
+            return None
+        if not os.path.exists(so):
+            # build beside the target, then rename: concurrent builders
+            # (test workers) never load a half-written file
+            tmp = f"{so}.{os.getpid()}.tmp"
             try:
                 subprocess.run(
                     ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-                     "-o", _SO, _SRC],
+                     "-o", tmp, _SRC],
                     check=True, capture_output=True, timeout=120)
-            except Exception:
+                os.replace(tmp, so)
+            except (OSError, subprocess.SubprocessError):
                 return None
-        if os.path.exists(_SO):
-            try:
-                _lib = _bind(ctypes.CDLL(_SO))
-            except OSError:
-                _lib = None
+        try:
+            _lib = _bind(ctypes.CDLL(so))
+        except OSError:
+            _lib = None
     return _lib
 
 

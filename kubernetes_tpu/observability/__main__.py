@@ -33,12 +33,6 @@ def _record_drain(n_nodes: int, n_pods: int, profile: str, chunk: int,
                   overlap: bool, warm: bool):
     """One pipelined drain with the recorder armed; returns
     (events, elapsed_s, totals, scheduler)."""
-    # persistent compile cache, same discipline as bench.py: set before
-    # the first jax import traces a kernel
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))), ".jax_cache"))
     from kubernetes_tpu.engine.scheduler import Scheduler
     from kubernetes_tpu.models.hollow import (
         PROFILES,
@@ -70,6 +64,8 @@ def _record_drain(n_nodes: int, n_pods: int, profile: str, chunk: int,
 
 
 def main(argv=None) -> int:
+    from kubernetes_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m kubernetes_tpu.observability",
         description="flight-recorder CLI: record a pipelined drain and "

@@ -15,26 +15,23 @@ cube never exists, each (bp, bn) output tile is produced from one
 Semantics are bit-identical to resources_fit (the scratch/overlay
 fallback of predicates.go:590-604 included); `resources_fit_fast`
 dispatches to the kernel on TPU backends and to the reference jnp path
-elsewhere, and the tests pin kernel-vs-jnp equality in interpret mode.
+on the CPU test backend, and the tests pin kernel-vs-jnp equality in
+interpret mode. Each dispatch counts its branch and shape at trace time
+(`kernel.<op>.<branch>[<shape>]` in utils.trace.COUNTERS), so a run can
+show which branch every compiled shape took.
 """
 
 from __future__ import annotations
 
-import os
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
 
 from kubernetes_tpu.state.snapshot import R_OVERLAY, R_SCRATCH
-
-try:  # pallas is TPU-oriented; keep import failures non-fatal (CPU CI)
-    from jax.experimental import pallas as pl
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    pl = None
-    _HAVE_PALLAS = False
+from kubernetes_tpu.utils.trace import COUNTERS
 
 P_BLK = 128
 N_BLK = 256
@@ -102,7 +99,6 @@ def capacity_fits_pallas(pod_req: jnp.ndarray, alloc: jnp.ndarray,
     alloc_t = _pad_to(_pad_to(alloc, 1, r_pad).T, 1, N_BLK)
     req_t = _pad_to(_pad_to(requested, 1, r_pad).T, 1, N_BLK)
     pp, nn = pod_p.shape[0], alloc_t.shape[1]
-    import functools
     out = pl.pallas_call(
         functools.partial(_capacity_kernel, n_res=n_res),
         out_shape=jax.ShapeDtypeStruct((pp, nn), jnp.int32),
@@ -182,13 +178,13 @@ def precompute_static_fast(aff, labels: jnp.ndarray,
     Stacking the three einsums into ONE tiled matmul dominates at small
     class counts (the common case: density batches have few classes) and
     never loses at large ones — so unlike resources_fit_fast (where the
-    measurement said sub-tile shapes lose), the gate here is simply
-    "pallas available on a TPU backend". Off-TPU the reference jnp path
-    runs."""
+    measurement said sub-tile shapes lose), the gate here is simply "a
+    TPU backend". On the CPU test backend the reference jnp path runs."""
     from kubernetes_tpu.ops.affinity import precompute_static
     c, s, l = aff["aff_allow"].shape
     n = labels.shape[0]
-    use = force if force is not None else _use_pallas()
+    use = force if force is not None else _on_tpu()
+    _count_branch("precompute_static", use, (c, s, l, n))
     if not use:
         return precompute_static(aff, labels)
     # one [C*(S+2), L] stack: allow terms, then forbid, then prio rows —
@@ -206,30 +202,50 @@ def precompute_static_fast(aff, labels: jnp.ndarray,
             "prio_counts": prio_counts}
 
 
-def _use_pallas() -> bool:
-    env = os.environ.get("KT_PALLAS", "")
-    if env in ("0", "off", "false"):
-        return False
-    if env in ("1", "on", "true"):
-        # an explicit opt-in still cannot run without the pallas import
-        return _HAVE_PALLAS
-    return _HAVE_PALLAS and jax.default_backend() == "tpu"
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _count_branch(op: str, kernel: bool, shape) -> None:
+    """Trace-time record of the branch one dispatch took at one shape."""
+    dims = "x".join(str(int(d)) for d in shape)
+    branch = "pallas" if kernel else "reference"
+    COUNTERS.inc(f"kernel.{op}.{branch}[{dims}]")
+
+
+def _capacity_fits(pod_req, alloc, requested, interpret, mesh):
+    """capacity_fits_pallas, per node shard under shard_map when the node
+    arrays are sharded over `mesh` in a GSPMD program: XLA cannot
+    partition a Mosaic kernel by itself."""
+    if mesh is None:
+        return capacity_fits_pallas(pod_req, alloc, requested,
+                                    interpret=interpret)
+    from jax.sharding import PartitionSpec as PS
+    ax = mesh.axis_names[0]
+    return jax.shard_map(
+        functools.partial(capacity_fits_pallas, interpret=interpret),
+        mesh=mesh, in_specs=(PS(), PS(ax), PS(ax)),
+        out_specs=PS(None, ax), check_vma=False)(pod_req, alloc, requested)
 
 
 def resources_fit_fast(pod_req: jnp.ndarray, zero_req: jnp.ndarray,
                        alloc: jnp.ndarray, requested: jnp.ndarray,
                        force: Optional[bool] = None,
-                       interpret: bool = False) -> jnp.ndarray:
+                       interpret: bool = False,
+                       mesh=None) -> jnp.ndarray:
     """Drop-in for predicates.resources_fit: Pallas-tiled on TPU, the
     reference jnp path elsewhere (and for sub-tile batches where tile
     padding would dominate). The zero-request override (predicates.go
     :576-578) composes outside the kernel — a [P,N] op XLA fuses into
-    the surrounding AND-chain either way."""
+    the surrounding AND-chain either way. `mesh` is the node-axis mesh
+    alloc/requested are sharded over when the caller is a GSPMD program
+    (not already inside shard_map)."""
+    shape = (pod_req.shape[0], alloc.shape[0], pod_req.shape[1])
     if force:
         # explicit force bypasses the size gate — the tests rely on it to
         # actually exercise the kernel on small hand cases
-        fit = capacity_fits_pallas(pod_req, alloc, requested,
-                                   interpret=interpret)
+        _count_branch("resources_fit", True, shape)
+        fit = _capacity_fits(pod_req, alloc, requested, interpret, mesh)
         return fit | zero_req[:, None]
     # per-dimension gate, set by MEASUREMENT (density bench A/B): the
     # kernel only pays off when both axes fill their tiles — the one-shot
@@ -238,10 +254,11 @@ def resources_fit_fast(pod_req: jnp.ndarray, zero_req: jnp.ndarray,
     # [N,R]->[R,N] transpose made waves 40-70% slower than the jnp path
     # XLA already fuses (0.83-1.17s vs 0.52-0.56s), so sub-tile axes
     # stay on the reference path.
-    if force is None and _use_pallas() \
-            and pod_req.shape[0] >= P_BLK and alloc.shape[0] >= N_BLK:
-        fit = capacity_fits_pallas(pod_req, alloc, requested,
-                                   interpret=interpret)
+    use = force is None and _on_tpu() \
+        and pod_req.shape[0] >= P_BLK and alloc.shape[0] >= N_BLK
+    _count_branch("resources_fit", use, shape)
+    if use:
+        fit = _capacity_fits(pod_req, alloc, requested, interpret, mesh)
         return fit | zero_req[:, None]
     from kubernetes_tpu.ops.predicates import resources_fit
     return resources_fit(pod_req, zero_req, alloc, requested)

@@ -38,8 +38,14 @@ _NODE_SHARDED_KEYS = frozenset({
 
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
+    """A 1-D node-axis mesh over the first `n_devices` devices (all when
+    None). Asking for more devices than exist is an error, never a
+    smaller mesh."""
     devs = jax.devices()
     if n_devices is not None:
+        if n_devices > len(devs):
+            raise ValueError(f"make_mesh({n_devices}): only {len(devs)} "
+                             f"{devs[0].platform} devices")
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (NODE_AXIS,))
 

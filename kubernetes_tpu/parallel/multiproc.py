@@ -49,6 +49,12 @@ _OWNER_RE = re.compile(r"already (?:claimed on|assigned to node) (\S+)")
 # are exact regardless — this only bounds the queue payload
 MAX_EVENTS_PER_WORKER = 4096
 
+# the JAX platform each worker runs on, written into its config: a chip
+# belongs to one process, and the parent holds it whenever it has one, so
+# the fleet is a host-only concurrency cell. Every worker's result names
+# the platform it actually ran on.
+WORKER_PLATFORM = "cpu"
+
 
 def audit_duplicate_binds(api, prefix: str = "") -> int:
     """STORE-TRUTH exactly-once audit over the full event log: a pod
@@ -73,10 +79,11 @@ def _worker_main(cfg: Dict, out_q) -> None:
     are the same seams the wave engine drives), hydrated by RELIST and
     committed-to only AFTER the shared cell accepted the fenced bind.
     """
-    # before any kubernetes_tpu import: the evaluator pulls in jax, and
-    # a CI worker must never grab an accelerator the parent owns
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # before any kubernetes_tpu import: the evaluator pulls in jax
+    os.environ["JAX_PLATFORMS"] = cfg["platform"]
     import random
+
+    import jax
 
     from kubernetes_tpu.client.binarywire import (
         BinaryWireClient, WireDeadline, WireError, WireOverloaded)
@@ -84,6 +91,7 @@ def _worker_main(cfg: Dict, out_q) -> None:
     from kubernetes_tpu.server.extender import TPUExtenderBackend
 
     wid = cfg["worker_id"]
+    platform = jax.devices()[0].platform
     rng = random.Random((0xED6A << 4) ^ (wid * 7919))
     pods = framing.decode_items_blob(cfg["pods_blob"], "pods")
     local = TPUExtenderBackend(
@@ -222,12 +230,12 @@ def _worker_main(cfg: Dict, out_q) -> None:
                 since_relist = 0
                 relist()  # the watch cadence: bounded staleness
         t_end = time.monotonic()
-        out_q.put({"worker": wid, "ok": True, "counts": counts,
-                   "bound": bound, "events": events,
+        out_q.put({"worker": wid, "ok": True, "platform": platform,
+                   "counts": counts, "bound": bound, "events": events,
                    "t0": t_start, "t1": t_end,
                    "elapsed_s": t_end - t_start})
     except Exception as e:  # noqa: BLE001 — report, never hang the join
-        out_q.put({"worker": wid, "ok": False,
+        out_q.put({"worker": wid, "ok": False, "platform": platform,
                    "error": f"{type(e).__name__}: {e}",
                    "counts": counts, "bound": bound, "events": events,
                    "t0": 0.0, "t1": 0.0, "elapsed_s": 0.0})
@@ -313,7 +321,8 @@ def run_process_fleet(n_workers: int, pods_per_worker: int = 64,
     try:
         for w in range(n_workers):
             pool = own[w] + shared  # shared pods raced by everyone
-            cfg = {"worker_id": w, "host": "127.0.0.1",
+            cfg = {"worker_id": w, "platform": WORKER_PLATFORM,
+                   "host": "127.0.0.1",
                    "port": srv.port,
                    "pods_blob": framing.encode_items_blob(pool, "pods"),
                    "stale_window_ms": stale_window_ms,
@@ -367,6 +376,7 @@ def run_process_fleet(n_workers: int, pods_per_worker: int = 64,
              if "bind_conflict_reason_" in k}
     agg = {
         "workers": n_workers,
+        "platforms": sorted({r["platform"] for r in results}),
         "pods_per_worker": pods_per_worker,
         "overlap": overlap,
         "n_nodes": n_nodes,

@@ -162,6 +162,8 @@ def profile_pipeline():
 
 
 def main():
+    from kubernetes_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if os.environ.get("PROFILE_EXTENDER") == "1":
         profile_extender()
         return

@@ -141,6 +141,22 @@ def test_warm_path_agrees_with_stateless_args_mode(backend):
     assert warm_scores == args_scores
 
 
+def test_device_scores_equal_the_oracle_for_an_unselected_pod(backend):
+    """No workload selects the pod: selector_spreading.go gives every node
+    MaxPriority, so the device route's integer scores equal the exact
+    oracle's (they once sat SelectorSpread's weight x 10 below it)."""
+    from kubernetes_tpu.ops import oracle
+    from kubernetes_tpu.ops.oracle_ext import SchedulingContext
+    pod = _pod("spread-free")
+    passed, _ = backend.filter(pod, None, None)
+    got = dict(backend.prioritize(pod, None, passed))
+    infos = backend.cache.node_infos()
+    want = oracle.prioritize(pod, [infos[nm] for nm in passed],
+                             backend.engine.priorities,
+                             SchedulingContext(infos, []))
+    assert got == dict(zip(passed, want))
+
+
 def test_affinity_sync_demotes_the_aff_free_lane(backend):
     """The /bind wire carries identifiers only, so affinity knowledge
     arrives with the BULK SYNC: once a synced bound pod carries

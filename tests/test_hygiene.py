@@ -9,7 +9,8 @@ Equivalents here:
   (file:line strings in docstrings are parity citations, not code);
 - every ktctl cmd_* verb is reachable through run()'s dispatch;
 - the wire KIND_REGISTRY and the apiserver KIND_INFO agree on the kinds
-  both layers must serve.
+  both layers must serve;
+- one helper (utils/compile_cache.py) places JAX's compilation cache.
 """
 
 import importlib
@@ -69,3 +70,30 @@ def test_wire_registry_covers_served_kinds():
     # break the REST facade on first touch
     missing = [k for k in KIND_INFO if k not in KIND_REGISTRY]
     assert not missing, missing
+
+
+def test_one_helper_places_the_compile_cache(monkeypatch, tmp_path):
+    """Only utils/compile_cache.py sets the cache directory; it follows
+    $JAX_COMPILATION_CACHE_DIR when set, else the checkout's .jax_cache."""
+    import jax
+
+    from kubernetes_tpu.utils import compile_cache
+
+    repo = ROOT.parent
+    setters = [
+        str(path.relative_to(repo))
+        for path in [*ROOT.rglob("*.py"), *repo.glob("*.py")]
+        if path.name != "compile_cache.py"
+        and ("jax_compilation_cache_dir" in path.read_text()
+             or "JAX_COMPILATION_CACHE_DIR" in path.read_text())]
+    assert not setters, setters
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable_compile_cache() == str(
+            repo / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -261,7 +261,6 @@ def test_two_stage_tie_select_matches_global():
     must equal _GlobalCol's whole-axis tiemat lookup for every (class,
     draw) — including empty tie sets and ties straddling shard
     boundaries."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     from kubernetes_tpu.engine.waves import _GlobalCol, _ShardCol
@@ -282,10 +281,10 @@ def test_two_stage_tie_select_matches_global():
 
     mesh = make_mesh(N_DEV)
     col = _ShardCol(NODE_AXIS, N, N // N_DEV)
-    got = shard_map(
+    got = jax.shard_map(
         lambda t, pc, k: col.tie_select(t, pc, k),
         mesh=mesh, in_specs=(PS(None, NODE_AXIS), PS(), PS()),
-        out_specs=PS(), check_rep=False)(ties_j, pod_class, kz)
+        out_specs=PS(), check_vma=False)(ties_j, pod_class, kz)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
 
@@ -408,3 +407,10 @@ def test_stream_sharded_equals_unsharded_frozen_trace():
     assert counters["engine.wave_encode_build"] == 0
     assert counters["engine.shard_delta_rows"] > 0
     assert counters["snapshot.assume_delta_rows"] >= sum(trace)
+
+
+def test_make_mesh_refuses_more_devices_than_exist():
+    """A mesh smaller than asked for would silently run unsharded."""
+    with pytest.raises(ValueError, match="only"):
+        make_mesh(len(jax.devices()) + 1)
+    assert make_mesh(2).devices.size == 2

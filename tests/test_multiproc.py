@@ -316,6 +316,8 @@ def test_process_fleet_racing_overlapped_pool_exactly_once():
     assert agg["missing_workers"] == 0, agg
     assert agg["worker_failures"] == [], agg
     assert agg["duplicate_binds"] == 0  # the hard-zero bar
+    # host-only workers, named as such: never readable as a device result
+    assert agg["platforms"] == ["cpu"]
     # every contested pod landed exactly once at the store
     api = out["api"]
     bound_events: dict = {}

@@ -134,4 +134,18 @@ def test_make_builds_everything(tmp_path):
     subprocess.run(["make", "-C", os.path.join(ROOT, "build"), "all"],
                    check=True, capture_output=True, env=env, timeout=300)
     assert os.path.exists(os.path.join(ROOT, "build", "bin", "pause"))
-    assert os.path.exists(os.path.join(ROOT, "native", "libhostops.so"))
+    assert os.path.exists(native.so_path())
+
+
+def test_library_name_follows_source_content(tmp_path, monkeypatch):
+    """A library built from other source (a stale file left on disk) must
+    never be the one loaded: the name is keyed on the source's bytes."""
+    import hashlib
+    src = tmp_path / "hostops.cc"
+    src.write_bytes(b"int a;")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    first = native.so_path()
+    want = hashlib.sha256(b"int a;").hexdigest()[:16]
+    assert os.path.basename(first) == f"libhostops-{want}.so"
+    src.write_bytes(b"int b;")
+    assert native.so_path() != first
