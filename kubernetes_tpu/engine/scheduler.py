@@ -86,7 +86,11 @@ from kubernetes_tpu.server.apiserver_lite import (
 from kubernetes_tpu.state.cache import SchedulerCache
 from kubernetes_tpu.utils import features
 from kubernetes_tpu.utils.metrics import SchedulerMetrics
-from kubernetes_tpu.utils.trace import SCHEDULE_TRACE_THRESHOLD_S, Trace
+from kubernetes_tpu.utils.trace import (
+    SCHEDULE_TRACE_THRESHOLD_S,
+    Trace,
+    timed_span,
+)
 
 DEFAULT_SCHEDULER_NAME = "default-scheduler"
 
@@ -275,6 +279,12 @@ class Scheduler:
             return 0
         if not events:
             return 0
+        with timed_span("sched.sync"):
+            self._apply_events(events)
+        return len(events)
+
+    def _apply_events(self, events) -> None:
+        """The body of sync(): apply one watch batch to cache and queue."""
         confirms: List[Pod] = []
         fresh: List[Pod] = []  # ADDED unbound pods we are responsible for:
         # admitted columnar (one queue lock), flushed BEFORE confirms at
@@ -365,7 +375,6 @@ class Scheduler:
         except BaseException:
             self._rv = last_rv
             raise
-        return len(events)
 
     def sync_pods_sip(self) -> int:
         """Drain ONLY the leading run of simple pod events — fresh
@@ -600,46 +609,47 @@ class Scheduler:
                 placed.append(r)
         # one batched /binding pass (per-binding semantics identical to the
         # per-pod POST; scheduler.go:224-250 error paths preserved per pod)
-        tb0 = time.monotonic()
-        errs = self.api.bind_many(
-            [Binding(r.pod.name, r.pod.namespace, r.pod.uid, r.node_name)
-             for r in placed])
-        bind_done = time.monotonic()
-        t_bind = bind_done - tb0
-        bound_pods, n_errors = self._finish_binds(
-            [r.pod for r in placed], errs)
+        with timed_span("bind.flush") as flush:
+            errs = self.api.bind_many(
+                [Binding(r.pod.name, r.pod.namespace, r.pod.uid, r.node_name)
+                 for r in placed])
+            bind_done = time.monotonic()
+            t_bind = bind_done - flush.t0
+            bound_pods, n_errors = self._finish_binds(
+                [r.pod for r in placed], errs)
+            stats["bind_errors"] += n_errors
+            stats["bound"] += len(bound_pods)
+            trace.step("bindings written")
+            self.cache.finish_bindings_bulk(bound_pods)
+            n = len(bound_pods)
+            self.metrics.scheduled.inc(n)
+            # honest spans (not amortized t/n): every pod in the batch
+            # really waited the FULL algorithm span and the FULL binding
+            # span — its placement was not done until the round's was.
+            # e2e matches the reference's pop->bind-complete window
+            # (scheduler.go:289)
+            self.metrics.algorithm_latency.observe_many(t_alg, n)
+            self.metrics.binding_latency.observe_many(t_bind, n)
+            self.metrics.e2e_latency.observe_many(bind_done - pop_ts, n)
+            # per-pod create->bound, queue wait + backoff rounds included:
+            # distinct value per pod, the distribution the SLO check reads
+            lats = [bind_done - self._first_queued.pop(p.key(), pop_ts)
+                    for p in bound_pods]
+            self.metrics.create_to_bound.observe_batch(lats)
+            if SLO.enabled and lats:
+                # the SLO engine sees EVERY bound pod (not the tracer's
+                # sampled subset) — burn-rate math over the full population
+                SLO.observe_batch(lats, t=bind_done)
+            if TRACER.enabled and bound_pods:
+                TRACER.bound_batch([p.key() for p in bound_pods],
+                                   t0=bind_done)
         if placed and RECORDER.enabled:
-            RECORDER.record(flightrec.BIND_FLUSH, t0=tb0, dur=t_bind,
+            RECORDER.record(flightrec.BIND_FLUSH, t0=flush.t0, dur=flush.dur,
                             a=len(bound_pods), b=n_errors)
-        stats["bind_errors"] += n_errors
-        stats["bound"] += len(bound_pods)
-        trace.step("bindings written")
-        self.cache.finish_bindings_bulk(bound_pods)
         if unschedulable_pods and features.enabled("PodPriority"):
             # after the binding pass, so a victim choice can never race a
             # not-yet-posted Binding from this same round
             stats["preemptions"] = self._preempt_round(unschedulable_pods)
-        n = len(bound_pods)
-        self.metrics.scheduled.inc(n)
-        # honest spans (not amortized t/n): every pod in the batch really
-        # waited the FULL algorithm span and the FULL binding span — its
-        # placement was not done until the round's was. e2e matches the
-        # reference's pop->bind-complete window (scheduler.go:289)
-        self.metrics.algorithm_latency.observe_many(t_alg, n)
-        self.metrics.binding_latency.observe_many(t_bind, n)
-        self.metrics.e2e_latency.observe_many(bind_done - pop_ts, n)
-        # per-pod create->bound, queue wait + backoff rounds included:
-        # distinct value per pod, the distribution the SLO check reads
-        lats = [bind_done - self._first_queued.pop(p.key(), pop_ts)
-                for p in bound_pods]
-        self.metrics.create_to_bound.observe_batch(lats)
-        if SLO.enabled and lats:
-            # the SLO engine sees EVERY bound pod (not the tracer's
-            # sampled subset) — burn-rate math over the full population
-            SLO.observe_batch(lats, t=bind_done)
-        if TRACER.enabled and bound_pods:
-            TRACER.bound_batch([p.key() for p in bound_pods],
-                               t0=bind_done)
         if self.wave_observer is not None and bound_pods:
             self.wave_observer(bind_done, [p.key() for p in bound_pods])
         self._idle_gc()
@@ -958,36 +968,39 @@ class Scheduler:
                                                handle.wave_id).items():
                     out[k] = out.get(k, 0) + v
             return out
-        tb0 = time.monotonic()
-        errs = self._bind_bulk(res.bound)
-        t_bind = time.monotonic() - tb0
-        bound_pods, n_errors = self._finish_binds(res.bound, errs)
-        out["bind_errors"] += n_errors
-        bind_done = time.monotonic()
+        with timed_span("bind.flush") as flush:
+            if flush.recording:
+                flush.annotate(wave=handle.wave_id, pods=len(res.bound))
+            errs = self._bind_bulk(res.bound)
+            t_bind = time.monotonic() - flush.t0
+            bound_pods, n_errors = self._finish_binds(res.bound, errs)
+            out["bind_errors"] += n_errors
+            bind_done = time.monotonic()
+            keys = [p.key() for p in bound_pods]  # computed once, shared
+            # by the TTL pass and the latency harvest below
+            self.cache.finish_bindings_bulk(bound_pods, keys=keys)
+            n = len(bound_pods)
+            out["bound"] = n
+            self.metrics.scheduled.inc(n)
+            # honest per-wave spans: algorithm = the residual device wait
+            # this wave's overlap did NOT hide; e2e = pop -> bind-complete
+            # including the one-wave pipeline lag every pod in the chunk
+            # really waited
+            self.metrics.algorithm_latency.observe_many(res.t_block, n)
+            self.metrics.binding_latency.observe_many(t_bind, n)
+            self.metrics.e2e_latency.observe_many(bind_done - handle.pop_ts,
+                                                  n)
+            fq_pop = self._first_queued.pop
+            pop_ts = handle.pop_ts
+            lats = [bind_done - fq_pop(k, pop_ts) for k in keys]
+            self.metrics.create_to_bound.observe_batch(lats)
+            if SLO.enabled:
+                SLO.observe_batch(lats, t=bind_done)
+            if TRACER.enabled:
+                TRACER.bound_batch(keys, t0=bind_done)
         if RECORDER.enabled:
             RECORDER.record(flightrec.BIND_FLUSH, wave=handle.wave_id,
-                            t0=tb0, dur=t_bind, a=len(bound_pods),
-                            b=n_errors)
-        keys = [p.key() for p in bound_pods]  # computed once, shared by the
-        # TTL pass and the latency harvest below
-        self.cache.finish_bindings_bulk(bound_pods, keys=keys)
-        n = len(bound_pods)
-        out["bound"] = n
-        self.metrics.scheduled.inc(n)
-        # honest per-wave spans: algorithm = the residual device wait this
-        # wave's overlap did NOT hide; e2e = pop -> bind-complete including
-        # the one-wave pipeline lag every pod in the chunk really waited
-        self.metrics.algorithm_latency.observe_many(res.t_block, n)
-        self.metrics.binding_latency.observe_many(t_bind, n)
-        self.metrics.e2e_latency.observe_many(bind_done - handle.pop_ts, n)
-        fq_pop = self._first_queued.pop
-        pop_ts = handle.pop_ts
-        lats = [bind_done - fq_pop(k, pop_ts) for k in keys]
-        self.metrics.create_to_bound.observe_batch(lats)
-        if SLO.enabled:
-            SLO.observe_batch(lats, t=bind_done)
-        if TRACER.enabled:
-            TRACER.bound_batch(keys, t0=bind_done)
+                            t0=flush.t0, dur=flush.dur, a=n, b=n_errors)
         if self.wave_observer is not None:
             self.wave_observer(bind_done, keys)
         if preemptors:

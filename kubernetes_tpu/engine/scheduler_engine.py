@@ -2213,13 +2213,10 @@ class SchedulingEngine:
 
         if not pods:
             return None
-        # flight recorder (ISSUE 13): one host-side timestamp when armed,
-        # nothing at all when off — the event itself is emitted after the
-        # async launch, carrying only host scalars already in hand
-        _rec_t0 = _time.monotonic() if RECORDER.enabled else 0.0
-        with timed_span("pipeline.dispatch"):
+        with timed_span("pipeline.dispatch") as span:
             infos = self._refresh()
-            out = self._wave_encoding(pods, infos)
+            with timed_span("pipeline.encode"):
+                out = self._wave_encoding(pods, infos)
             if out is None:
                 return None
             enc, pc = out
@@ -2325,25 +2322,29 @@ class SchedulingEngine:
             if gangs:
                 COUNTERS.inc("engine.gang_wave_dispatch", len(gangs))
             wave_id = -1
-            if RECORDER.enabled or TRACER.enabled:
-                # one wave-id sequence for BOTH observers, so a pod's
+            if RECORDER.enabled or TRACER.enabled or span.recording:
+                # one wave-id sequence for every observer, so a pod's
                 # WAVE_DISPATCHED joins the ring's dispatch/harvest
-                # events on the exported timeline
+                # events and the profiler's spans of the same wave
                 wave_id = RECORDER.next_wave()
-            if _rec_t0 and RECORDER.enabled:
-                RECORDER.record(flightrec.DISPATCH, wave=wave_id,
-                                t0=_rec_t0,
-                                dur=_time.monotonic() - _rec_t0,
-                                a=n, b=len(gangs) if gangs else 0)
+                if span.recording:
+                    span.annotate(wave=wave_id, pods=n)
             if TRACER.enabled:
                 TRACER.batch_event(podtrace.WAVE_DISPATCHED,
                                    [p.key() for p in pods], a=wave_id)
-            return WaveHandle(list(pods), pc, enc, packed, state_out,
-                              counter_out, nodes, blind, pop_ts,
-                              _time.monotonic(), self.wave_pad_floor,
-                              committed_out=committed_out,
-                              strict_idx=strict_idx, gangs=gangs,
-                              wave_id=wave_id, host_idx=host_idx)
+            handle = WaveHandle(list(pods), pc, enc, packed, state_out,
+                                counter_out, nodes, blind, pop_ts,
+                                _time.monotonic(), self.wave_pad_floor,
+                                committed_out=committed_out,
+                                strict_idx=strict_idx, gangs=gangs,
+                                wave_id=wave_id, host_idx=host_idx)
+        if RECORDER.enabled:
+            # the flight recorder takes the dispatch span's own stamps,
+            # emitted after the async launch with host scalars in hand
+            RECORDER.record(flightrec.DISPATCH, wave=wave_id, t0=span.t0,
+                            dur=span.dur, a=n,
+                            b=len(gangs) if gangs else 0)
+        return handle
 
     def harvest_waves(self, handle: WaveHandle) -> WaveHarvest:
         """Block on one wave's device→host sync, fence its placements
@@ -2354,11 +2355,30 @@ class SchedulingEngine:
         conservatively when their node was touched in the blind window.
         Conflicting pods are returned for requeue WITHOUT backoff — they
         lost a capacity race, they are not unschedulable."""
+        from kubernetes_tpu.utils.trace import timed_span
+
+        with timed_span("pipeline.harvest") as span:
+            if span.recording:
+                span.annotate(wave=handle.wave_id, pods=len(handle.pods))
+            res = self._harvest(handle)
+        if RECORDER.enabled:
+            # the flight recorder takes the harvest span's own stamps
+            RECORDER.record(flightrec.HARVEST, wave=handle.wave_id,
+                            t0=span.t0, dur=span.dur, a=len(res.bound),
+                            b=len(res.conflicts)
+                            + len(res.liveness_requeued))
+            if res.conflicts or res.liveness_requeued:
+                RECORDER.record(flightrec.FENCE_REQUEUE,
+                                wave=handle.wave_id, a=len(res.conflicts),
+                                b=len(res.liveness_requeued))
+        return res
+
+    def _harvest(self, handle: WaveHandle) -> WaveHarvest:
+        """The body of harvest_waves, inside its span."""
         import time as _time
 
         from kubernetes_tpu.utils.trace import COUNTERS, timed_span
 
-        _rec_t0 = _time.monotonic() if RECORDER.enabled else 0.0
         # the fence below compares against snapshot arrays — fold in any
         # commits/events since the last dispatch (hinted: near-free when
         # nothing moved)
@@ -2374,16 +2394,11 @@ class SchedulingEngine:
             self._try_patch_foreign(enc)
         n = len(handle.pods)
         p_pad = bucket(max(n, handle.pad_floor or 1))
-        t0 = _time.perf_counter()
-        with timed_span("pipeline.device_block"):
+        with timed_span("pipeline.device_block") as block:
             # THE pipeline's blessed block: harvest exists to absorb this
             # wave's device wait while the NEXT wave already runs
             packed_h = np.asarray(handle.packed)  # graftlint: sync-ok
-        t_block = _time.perf_counter() - t0
-        # block-END instant on the ring's timebase: the device-eval lane's
-        # right edge (the exporter reconstructs the window as
-        # [dispatch end → this instant])
-        _rec_block_end = _time.monotonic() if _rec_t0 else 0.0
+        t_block = block.dur
         # the per-wave device->host payload: [3P+2] int32 regardless of N —
         # the scale_sweep's proof that harvesting never fetches node-axis
         # tensors (the winner reduce already collapsed them on device)
@@ -2664,18 +2679,9 @@ class SchedulingEngine:
                             bound.append(pods[i])
                         else:
                             unschedulable.append((pods[i], 0))
-        if _rec_t0 and RECORDER.enabled:
-            RECORDER.record(flightrec.HARVEST, wave=handle.wave_id,
-                            t0=_rec_block_end - t_block, dur=t_block,
-                            a=len(bound),
-                            b=len(conflicts) + len(liveness))
-            if conflicts or liveness:
-                RECORDER.record(flightrec.FENCE_REQUEUE,
-                                wave=handle.wave_id,
-                                a=len(conflicts), b=len(liveness))
         if TRACER.enabled:
             # per-pod harvest/fence stamps (ISSUE 15): survivors get
-            # HARVESTED (the device phase's right edge on their
+            # HARVESTED (the in_flight phase's right edge on their
             # timeline), losers a FENCE_REQUEUED carrying the typed
             # reason — host ints only, the sync above already happened
             t_h = _time.monotonic()

@@ -18,8 +18,7 @@ hidden sync):
   Every introspection transport — HTTP ``/debug/vars``, the binary
   wire's STATS verb, ``VerdictService.debug_snapshot`` — serves THIS.
 - ``perfetto``: a Chrome trace-event exporter rendering the recorder
-  ring as host / device / fence lanes, so the pipeline-overlap
-  attribution profile_bench.py approximates becomes a loadable
+  ring as host / fence / preempt / fastlane lanes, a loadable
   timeline (``python -m kubernetes_tpu.observability --trace out.json``
   then chrome://tracing or ui.perfetto.dev).
 

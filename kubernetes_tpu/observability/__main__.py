@@ -10,7 +10,7 @@
 --trace runs the pipelined drain (warmup pass first so compiles never
 pollute the window), records every wave, and writes the Chrome
 trace-event JSON — load it in chrome://tracing or ui.perfetto.dev to
-see the host-tail / device-eval overlap as lanes; with GRAFT_PODTRACE=1
+see each wave's dispatch, harvest and bind flush; with GRAFT_PODTRACE=1
 the tracer's tail-exemplar pods render as additional per-pod phase
 lanes. --events dumps the raw recorder ring instead; --vars prints a
 telemetry-registry snapshot of the recorded run. --trend renders the
@@ -127,7 +127,6 @@ def main(argv=None) -> int:
         from kubernetes_tpu.observability.perfetto import (
             add_pod_lanes,
             build_chrome_trace,
-            overlap_seconds,
         )
         from kubernetes_tpu.observability.podtrace import TRACER
         trace = build_chrome_trace(events)
@@ -144,11 +143,8 @@ def main(argv=None) -> int:
         with open(args.trace, "w", encoding="utf-8") as f:
             json.dump(trace, f)
             f.write("\n")
-        hidden = overlap_seconds(events)
         print(f"wrote {args.trace}: {len(trace['traceEvents'])} trace "
-              f"events ({n_pods} exemplar pod lanes), "
-              f"{hidden * 1e3:.1f}ms of host work hidden under "
-              f"device-eval windows", file=sys.stderr)
+              f"events ({n_pods} exemplar pod lanes)", file=sys.stderr)
     if args.events:
         with open(args.events, "w", encoding="utf-8") as f:
             json.dump(events, f, indent=1)

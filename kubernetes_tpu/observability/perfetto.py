@@ -1,27 +1,30 @@
 """Chrome trace-event (Perfetto) exporter for the flight recorder ring.
 
-Renders the recorder's per-wave events as a timeline with three lanes —
-the attribution profile_bench.py approximates with wrapped functions
-becomes a picture you load in chrome://tracing or ui.perfetto.dev:
+Renders the recorder's per-wave events as a timeline of host lanes —
+a picture of the scheduling loop you load in chrome://tracing or
+ui.perfetto.dev, served by /debug/trace and
+``python -m kubernetes_tpu.observability``:
 
 - **host** lane: per-wave dispatch spans (encode reuse, patch flush,
-  upload) and bind-flush spans (the bulk write + result tail) — the
-  host tail wave k+1's device time is supposed to hide.
-- **device** lane: per-wave device-eval windows, reconstructed from the
-  recorder's own stamps as [dispatch end → harvest block end] — exactly
-  the async window JAX owns the wave for. With the pipeline two deep,
-  wave k+1's device span visibly overlaps wave k's bind-flush on the
-  host lane; in `overlap=False` debug mode the lanes serialize. That
-  picture IS the r14 overlap attribution, automated.
+  upload), harvest spans (device wait, tail, fence, assume) and
+  bind-flush spans (the bulk write + result tail), each on the stamps
+  of the engine's own ``pipeline.dispatch`` / ``pipeline.harvest`` /
+  ``bind.flush`` spans.
 - **fence** lane: instant markers for fence requeues, Protean patches,
   degraded-mode transitions, churn ops and SLO-alert flips — the churn
   story lands on the same time axis as the waves it perturbed.
+- **preempt** and **fastlane** lanes: victim selection, commits and
+  fast-lane binds.
 
-Flow arrows (ISSUE 15 satellite): every wave's dispatch → device-eval →
+The device's own timeline is the profiler trace (``jax.profiler``),
+which carries the same spans by name on its host plane beside the
+device ops; this exporter draws no device lane.
+
+Flow arrows: every wave's dispatch → harvest →
 bind-flush chain carries Chrome flow events (``ph`` s/t/f with the wave
-id), so following one wave across the host and device lanes is a click,
-not a visual scan; the span events carry ``span_ms`` args alongside
-their pod counts.
+id), so following one wave through the pipeline's interleaving is a
+click, not a visual scan; the span events carry ``span_ms`` args
+alongside their pod counts.
 
 Pod lanes (ISSUE 15): ``add_pod_lanes`` renders the tracer's slowest-K
 tail exemplars as one lane per pod — each consecutive-event delta drawn
@@ -44,14 +47,12 @@ from kubernetes_tpu.observability import recorder as rec
 
 PID = 1
 TID_HOST = 1
-TID_DEVICE = 2
 TID_FENCE = 3
 TID_PREEMPT = 4
 TID_FASTLANE = 5
 
-_THREADS = ((TID_HOST, "host"), (TID_DEVICE, "device"),
-            (TID_FENCE, "fence"), (TID_PREEMPT, "preempt"),
-            (TID_FASTLANE, "fastlane"))
+_THREADS = ((TID_HOST, "host"), (TID_FENCE, "fence"),
+            (TID_PREEMPT, "preempt"), (TID_FASTLANE, "fastlane"))
 
 
 def build_chrome_trace(events: List[Dict]) -> Dict:
@@ -70,15 +71,12 @@ def build_chrome_trace(events: List[Dict]) -> Dict:
     def us(t: float) -> float:
         return round((t - t_base) * 1e6, 1)
 
-    # device lane windows need the dispatch/harvest pair per wave id;
-    # the flow arrows (dispatch → device-eval → bind-flush of one wave)
-    # need an anchor instant inside each span
-    dispatch_end: Dict[int, float] = {}
+    # the flow arrows (dispatch → harvest → bind-flush of one wave) need
+    # an anchor instant inside each span
     flow_anchor: Dict[int, List] = {}  # wave -> [(tid, ts_us), ...]
     for e in events:
         kind = e["kind"]
         if kind == "dispatch":
-            dispatch_end[e["wave"]] = e["t"] + e["dur"]
             out.append({"ph": "X", "pid": PID, "tid": TID_HOST,
                         "name": f"dispatch w{e['wave']}",
                         "ts": us(e["t"]), "dur": round(e["dur"] * 1e6, 1),
@@ -87,20 +85,13 @@ def build_chrome_trace(events: List[Dict]) -> Dict:
             flow_anchor.setdefault(e["wave"], []).append(
                 (TID_HOST, us(e["t"])))
         elif kind == "harvest":
-            block_end = e["t"] + e["dur"]
-            start = dispatch_end.get(e["wave"], e["t"])
-            out.append({"ph": "X", "pid": PID, "tid": TID_DEVICE,
-                        "name": f"device-eval w{e['wave']}",
-                        "ts": us(start),
-                        "dur": max(round((block_end - start) * 1e6, 1),
-                                   0.1),
+            out.append({"ph": "X", "pid": PID, "tid": TID_HOST,
+                        "name": f"harvest w{e['wave']}",
+                        "ts": us(e["t"]), "dur": round(e["dur"] * 1e6, 1),
                         "args": {"bound": e["a"], "fenced": e["b"],
-                                 "span_ms": round((block_end - start)
-                                                  * 1e3, 3),
-                                 "residual_block_ms":
-                                     round(e["dur"] * 1e3, 3)}})
+                                 "span_ms": round(e["dur"] * 1e3, 3)}})
             flow_anchor.setdefault(e["wave"], []).append(
-                (TID_DEVICE, us(start)))
+                (TID_HOST, us(e["t"])))
         elif kind == "bind_flush":
             out.append({"ph": "X", "pid": PID, "tid": TID_HOST,
                         "name": f"bind-flush w{e['wave']}"
@@ -190,7 +181,7 @@ def build_chrome_trace(events: List[Dict]) -> Dict:
                         "ts": us(e["t"]),
                         "args": {"burn_fast_x100": e["b"]}})
     # flow arrows: one chain per wave through its recorded stages, in
-    # stage order (dispatch → device-eval → bind-flush). Chrome binds a
+    # stage order (dispatch → harvest → bind-flush). Chrome binds a
     # flow event to the slice enclosing (tid, ts), so each anchor is the
     # span's own start instant.
     for wave, anchors in sorted(flow_anchor.items()):
@@ -311,7 +302,7 @@ def add_pod_lanes(trace: Dict, exemplars: List[Dict],
 
 def export_chrome_trace(events: List[Dict], path: str) -> Dict:
     """Write the Chrome trace JSON for a recorder snapshot; returns the
-    trace dict (tests assert on lanes/overlap without re-reading)."""
+    trace dict (tests assert on lanes without re-reading)."""
     trace = build_chrome_trace(events)
     with open(path, "w", encoding="utf-8") as f:
         json.dump(trace, f)
@@ -319,63 +310,5 @@ def export_chrome_trace(events: List[Dict], path: str) -> Dict:
     return trace
 
 
-def overlap_seconds(events: List[Dict]) -> float:
-    """Total host-work seconds hidden under device-eval windows — the
-    quantitative half of the overlap picture (the r14 attribution as a
-    number): sum over host spans of their intersection with device-eval
-    windows of OTHER waves. O(n log n): host spans intersect the merged
-    union of device windows, minus the same wave's own window (one batch
-    owns the device at a time, so a wave's window overlapping another
-    wave's is negligible — and a full-ring export must not pay an
-    all-pairs Python loop over tens of thousands of spans)."""
-    import bisect
-
-    device: List = []
-    host: List = []
-    dispatch_end: Dict[int, float] = {}
-    dev_by_wave: Dict[int, tuple] = {}
-    for e in events:
-        if e["kind"] == "dispatch":
-            dispatch_end[e["wave"]] = e["t"] + e["dur"]
-            host.append((e["t"], e["t"] + e["dur"], e["wave"]))
-        elif e["kind"] == "harvest":
-            start = dispatch_end.get(e["wave"], e["t"])
-            device.append((start, e["t"] + e["dur"]))
-            dev_by_wave[e["wave"]] = (start, e["t"] + e["dur"])
-        elif e["kind"] == "bind_flush":
-            host.append((e["t"], e["t"] + e["dur"], e["wave"]))
-    if not device or not host:
-        return 0.0
-    # merged union of device windows + prefix lengths for O(log n) probes
-    device.sort()
-    merged = [list(device[0])]
-    for d0, d1 in device[1:]:
-        if d0 <= merged[-1][1]:
-            merged[-1][1] = max(merged[-1][1], d1)
-        else:
-            merged.append([d0, d1])
-    starts = [m[0] for m in merged]
-    prefix = [0.0]
-    for m0, m1 in merged:
-        prefix.append(prefix[-1] + (m1 - m0))
-
-    def measure_upto(x: float) -> float:
-        """Union length of the merged device windows within (-inf, x]."""
-        k = bisect.bisect_right(starts, x) - 1
-        if k < 0:
-            return 0.0
-        m0, m1 = merged[k]
-        return prefix[k] + min(max(x - m0, 0.0), m1 - m0)
-
-    total = 0.0
-    for h0, h1, hw in host:
-        covered = measure_upto(h1) - measure_upto(h0)
-        own = dev_by_wave.get(hw)
-        if own is not None:
-            covered -= max(min(h1, own[1]) - max(h0, own[0]), 0.0)
-        total += min(max(covered, 0.0), h1 - h0)
-    return total
-
-
 __all__ = ["add_pod_lanes", "add_process_lanes", "build_chrome_trace",
-           "export_chrome_trace", "overlap_seconds"]
+           "export_chrome_trace"]

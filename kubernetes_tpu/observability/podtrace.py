@@ -52,7 +52,7 @@ Cost model (the reason this can stay armed in production):
 Completion feeds three consumers:
 
 - the CRITICAL-PATH aggregate: consecutive event deltas telescope into
-  named phases (queue_wait / requeue_wait / dispatch / device /
+  named phases (queue_wait / requeue_wait / dispatch / in_flight /
   bind_flush / fence / gang_wait / classic_round / wire / other) whose
   per-pod sum equals the pod's first-event->BOUND span EXACTLY (by
   construction — the phases are a partition of the timeline), summed
@@ -153,7 +153,7 @@ HOP_NAMES = ("filter", "bind")
 # fast_eval / fast_bind (ISSUE 17) decompose a fast-lane pod's span:
 # pop -> sampled eval, then eval -> bind-complete — the two halves of
 # the sub-10 ms budget, attributable separately
-PHASE_NAMES = ("queue_wait", "requeue_wait", "dispatch", "device",
+PHASE_NAMES = ("queue_wait", "requeue_wait", "dispatch", "in_flight",
                "bind_flush", "classic_round", "fence", "gang_wait",
                "wire", "fast_eval", "fast_bind", "other")
 
@@ -171,7 +171,10 @@ def phase_of(prev_k: int, k: int, requeued: bool) -> str:
     if k == FAST_DISPATCHED:
         return "fast_eval"  # pop -> sampled [1,k] eval (ISSUE 17)
     if k == HARVESTED:
-        return "device"
+        # dispatch -> harvest on the host clock: the device's work plus
+        # whatever host work ran while the wave was in flight (the
+        # device's own time is in the profiler trace, not here)
+        return "in_flight"
     if k == BOUND:
         if prev_k == HARVESTED:
             return "bind_flush"

@@ -30,19 +30,19 @@ fetching variant fires).
 Event kinds (the per-wave vocabulary of the pipelined engine):
 
     DISPATCH    one wave admitted + its fused eval launched async.
-                wave=id, a=pods admitted, b=gangs riding; dur=dispatch
-                host span (encode reuse, patch flush, upload).
+                wave=id, a=pods admitted, b=gangs riding; t/dur=the
+                pipeline.dispatch span (encode reuse, patch flush,
+                upload).
     HARVEST     one wave's device→host sync + fence + assume. wave=id,
                 a=pods bound, b=pods fenced (capacity+liveness);
-                t stamps the device-block START, dur=the residual
-                device block (pipeline.device_block) — so t+dur is the
-                device-eval lane's right edge.
+                t/dur=the pipeline.harvest span.
     FENCE_REQUEUE  the fence threw rows back. a=capacity conflicts,
                 b=liveness requeues.
     PATCH       Protean delta invalidation absorbed churn into the
                 cached encoding. a=foreign rows patched, b=label rows.
-    BIND_FLUSH  one bulk bind write. wave=id (-1 on the classic
-                round), a=pods bound, b=bind errors; dur=write span.
+    BIND_FLUSH  one bulk bind write and its bookkeeping. wave=id (-1 on
+                the classic round), a=pods bound, b=bind errors;
+                t/dur=the bind.flush span.
     DEGRADED    streaming loop mode transition. a=1 enter / 0 exit,
                 b=breach streak at the flip.
     CHURN_OP    one injected churn op applied (testing/churn.py).

@@ -78,6 +78,7 @@ from kubernetes_tpu.server.coalescer import (
     EvalCoalescer,
     Overloaded,
 )
+from kubernetes_tpu.utils.trace import timed_span
 
 
 class ExtenderBackend(Protocol):
@@ -97,6 +98,15 @@ class ExtenderBackend(Protocol):
     def sync_pods(self, pods: List[Pod]) -> None: ...
 
     def metrics_text(self) -> str: ...
+
+
+def _pod_key(payload: Dict) -> str:
+    """A verb's pod as "namespace/name", for the metadata of its spans:
+    the shipped pod's, else /bind's identifiers."""
+    meta = (payload.get("Pod") or payload.get("pod") or {}).get(
+        "metadata") or {}
+    ns = meta.get("namespace") or payload.get("PodNamespace") or ""
+    return f"{ns}/{meta.get('name') or payload.get('PodName') or ''}"
 
 
 class _FleetHTTPServer(ThreadingHTTPServer):
@@ -135,7 +145,23 @@ class ExtenderHTTPServer:
                 length = int(self.headers.get("Content-Length", 0))
                 return self.rfile.read(length) if length else b""
 
+            # the POST's pod ("namespace/name") while a profiler capture
+            # records its spans; "" otherwise
+            trace_pod = ""
+
             def _write_json(self, obj, code: int = 200, headers=None):
+                if self.command != "POST":
+                    # introspection stays untimed: a span counted while
+                    # serving /debug/vars would change the registry every
+                    # transport serves
+                    self._send_json(obj, code, headers)
+                    return
+                with timed_span("extender.http.encode") as span:
+                    if span.recording and self.trace_pod:
+                        span.annotate(pod=self.trace_pod)
+                    self._send_json(obj, code, headers)
+
+            def _send_json(self, obj, code, headers):
                 # compact separators: a 5k-node HostPriorityList is ~230KB
                 # of response; the default ", " padding costs measurable
                 # serialize+wire time at compat-mode request rates
@@ -222,6 +248,7 @@ class ExtenderHTTPServer:
                 # would desync every later request on the socket — the
                 # head-of-line audit of the ISSUE 9 satellite
                 raw = self._read_raw()
+                self.trace_pod = ""
                 try:
                     if path in ("/cache/nodes", "/cache/pods"):
                         # bulk sync: binary fast path (protobuf, SURVEY
@@ -276,7 +303,11 @@ class ExtenderHTTPServer:
                                 podtrace.HOP_FILTER if path == "/filter"
                                 else podtrace.HOP_BIND)
                     try:
-                        payload = json.loads(raw or b"{}")
+                        with timed_span("extender.http.decode") as span:
+                            payload = json.loads(raw or b"{}")
+                            if span.recording:
+                                self.trace_pod = _pod_key(payload)
+                                span.annotate(pod=self.trace_pod)
                         if path == "/filter":
                             out, code = outer.handle_filter(payload), 200
                         elif path == "/prioritize":
@@ -346,15 +377,17 @@ class ExtenderHTTPServer:
 
     def _parse_args(self, payload: Dict) -> Tuple[Pod, Optional[List[Node]],
                                                   Optional[List[str]]]:
-        pod_obj = self._get(payload, "Pod", "pod") or {}
-        pod = serde.decode_pod(pod_obj)
-        nodes_obj = self._get(payload, "Nodes", "nodes")
-        nodes = None
-        if nodes_obj:
-            nodes = [serde.decode_node(n)
-                     for n in (nodes_obj.get("Items")
-                               or nodes_obj.get("items") or [])]
-        names = self._get(payload, "NodeNames", "nodenames", "nodeNames")
+        with timed_span("extender.http.decode"):
+            pod_obj = self._get(payload, "Pod", "pod") or {}
+            pod = serde.decode_pod(pod_obj)
+            nodes_obj = self._get(payload, "Nodes", "nodes")
+            nodes = None
+            if nodes_obj:
+                nodes = [serde.decode_node(n)
+                         for n in (nodes_obj.get("Items")
+                                   or nodes_obj.get("items") or [])]
+            names = self._get(payload, "NodeNames", "nodenames",
+                              "nodeNames")
         return pod, nodes, names
 
     @staticmethod
@@ -426,8 +459,11 @@ class ExtenderHTTPServer:
                 or getattr(self.backend, "bind_verdict", None) is None:
             return {"Error": self.backend.bind(
                 pod_name, pod_ns, pod_uid, node)}, 200
+        spec = None
         spec_obj = self._get(payload, "Pod", "pod")
-        spec = serde.decode_pod(spec_obj) if spec_obj else None
+        if spec_obj:
+            with timed_span("extender.http.decode"):
+                spec = serde.decode_pod(spec_obj)
         gen = payload.get("SnapshotGen")
         res = self.service.bind(
             pod_name, pod_ns, pod_uid, node,
@@ -793,6 +829,15 @@ class TPUExtenderBackend:
 
     FAIL_REASON = "node(s) didn't satisfy TPU predicate kernel"
 
+    @staticmethod
+    def _lock_held(wait, pod_key: str) -> None:
+        """End an `extender.lock_wait` span once the verb holds `_lock`
+        (`with timed_span("extender.lock_wait") as wait, self._lock:`),
+        naming the verb's pod while a profiler capture records it."""
+        if wait.recording:
+            wait.annotate(pod=pod_key)
+        wait.stop()
+
     # ---- coalescer seams (ISSUE 9): the leader evaluates whole batches
     # under ONE lock acquisition; verdict objects capture names/index/gen
     # from the same critical section so responses build outside it -------
@@ -802,7 +847,8 @@ class TPUExtenderBackend:
         batch's unique classes (engine.evaluate_pods_batch). Returns one
         _Verdict per pod, in order."""
         from kubernetes_tpu.engine.scheduler_engine import evaluate_pods_batch
-        with self._lock:
+        with timed_span("extender.lock_wait") as wait, self._lock:
+            self._lock_held(wait, pods[0].key())
             infos = self._refresh_warm_locked()
             snap = self.engine.snapshot
             port_words = max(self._port_words_for(p) for p in pods)
@@ -821,7 +867,8 @@ class TPUExtenderBackend:
 
     def _eval_one(self, pod):
         """Degraded per-request fallback (coalescer fault path)."""
-        with self._lock:
+        with timed_span("extender.lock_wait") as wait, self._lock:
+            self._lock_held(wait, pod.key())
             snap, m, s = self._eval_locked(pod, None)
             return _Verdict(m, s, snap.node_names, snap.node_index,
                             self._snap_gen)
@@ -921,7 +968,8 @@ class TPUExtenderBackend:
         if nodes is not None:
             # non-cache-capable args-mode: full state ships per request —
             # nothing to coalesce against, evaluate directly
-            with self._lock:
+            with timed_span("extender.lock_wait") as wait, self._lock:
+                self._lock_held(wait, pod.key())
                 snap, m, _ = self._eval_locked(pod, nodes)
                 names = snap.node_names
                 idx = snap.node_index
@@ -933,7 +981,8 @@ class TPUExtenderBackend:
 
     def prioritize(self, pod, nodes, node_names):
         if nodes is not None:
-            with self._lock:
+            with timed_span("extender.lock_wait") as wait, self._lock:
+                self._lock_held(wait, pod.key())
                 snap, _, s = self._eval_locked(pod, nodes)
                 names = snap.node_names
                 idx = snap.node_index
@@ -1114,7 +1163,8 @@ class TPUExtenderBackend:
         import dataclasses
         import time as _time
         assumed_now = False
-        with self._lock:
+        with timed_span("extender.lock_wait") as wait, self._lock:
+            self._lock_held(wait, key)
             if deadline_s is not None \
                     and _time.monotonic() - t0 > deadline_s:
                 self._count("deadline_shed")
@@ -1186,7 +1236,9 @@ class TPUExtenderBackend:
                     # whose write fails must not forget a legitimately
                     # bound pod (that would leak its capacity until the
                     # next sync)
-                    with self._lock:
+                    with timed_span("extender.lock_wait") as wait, \
+                            self._lock:
+                        self._lock_held(wait, key)
                         self.cache.forget_pod(pod)
                         self._assumed_bare.pop(key, None)
                         self._bind_hint.add(node)

@@ -10,6 +10,7 @@ scheduler wraps every Schedule call at a 100ms threshold
 from __future__ import annotations
 
 import logging
+import sys
 import threading
 from kubernetes_tpu.analysis import lockcheck
 import time
@@ -68,21 +69,70 @@ class SpanCounters:
 COUNTERS = SpanCounters()
 
 
-class timed_span:
-    """`with timed_span("extender.refresh"): ...` — count + accumulate."""
+_annotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded
 
-    __slots__ = ("name", "_t0")
+
+def _load_annotation():
+    """The profiler's annotation class, or None while JAX is not loaded:
+    such a process has no profiler to record into, and a host-only
+    process (the trend CLI, the process-fleet parent) stays free of the
+    import."""
+    global _annotation
+    if "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class timed_span:
+    """`with timed_span("pipeline.harvest") as sp: ...` — count and
+    accumulate the span in COUNTERS, and open a profiler annotation
+    (a TraceMe) of the same name, so a profiler capture shows the span
+    on the host plane of the same trace as the device ops. With no
+    capture running the annotation costs a constructor and a flag check.
+
+    Stamps are `time.monotonic`, the clock of the flight recorder and of
+    the benchmark's `bench.anchor`: after the block, `sp.t0` and `sp.dur`
+    are the span's start and length, for callers that record the same
+    interval elsewhere. `sp.stop()` ends the span early (the block's exit
+    then does nothing more) — `with timed_span(...) as w, lock:
+    w.stop()` times the wait for a lock. Metadata for the profiler
+    event is built only while a capture records it:
+    `if sp.recording: sp.annotate(wave=wave_id)`."""
+
+    __slots__ = ("name", "t0", "dur", "_tm")
 
     def __init__(self, name: str):
         self.name = name
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        ann = _annotation or _load_annotation()
+        self._tm = ann(self.name) if ann is not None else None
+        self.dur = None
+        self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        COUNTERS.add_time(self.name, time.perf_counter() - self._t0)
+        self.stop()
         return False
+
+    def stop(self) -> None:
+        if self.dur is not None:
+            return
+        self.dur = time.monotonic() - self.t0
+        if self._tm is not None:
+            self._tm.__exit__(None, None, None)
+        COUNTERS.add_time(self.name, self.dur)
+
+    @property
+    def recording(self) -> bool:
+        """True while a profiler capture records this span."""
+        return self._tm is not None and self._tm.is_enabled()
+
+    def annotate(self, **meta) -> None:
+        """Attach `meta` to the span's profiler event (guard the call
+        with `recording`, so nothing is built when no capture runs)."""
+        self._tm.set_metadata(**meta)
 
 
 class Trace:

@@ -6,9 +6,8 @@ Pins the contracts the observability tentpole rests on:
   ordered, and an EXACT no-op when disabled — a drain with the recorder
   off emits zero events;
 - a pipelined drain records dispatch/harvest/bind-flush per wave with
-  matching wave ids, and the Perfetto exporter renders host/device/fence
-  lanes with the host-tail-under-device-eval overlap VISIBLE (the r14
-  attribution as data, not prose);
+  matching wave ids, and the Perfetto exporter renders them on the host
+  lane with the pipeline's interleaving VISIBLE, beside the fence lane;
 - the unified registry folds spans + SchedulerMetrics + service counters
   + gauges into one labeled namespace with a single Prometheus render
   (legacy metric names intact);
@@ -115,9 +114,12 @@ def test_drain_records_typed_waves_with_matching_ids(flight):
 
 
 def test_perfetto_export_lanes_and_overlap(flight, tmp_path):
-    """The exported timeline carries distinct host/device/fence lanes and
-    the pipelined overlap is VISIBLE: at least one wave's device-eval
-    window contains the previous wave's bind-flush."""
+    """The exported timeline carries the host/fence/preempt/fastlane
+    lanes and no device lane (the device's own timeline is the profiler
+    trace). Each wave's dispatch, harvest and bind-flush sit on the host
+    lane in stage order, and the pipelined overlap is VISIBLE as their
+    interleaving: some wave dispatches before the previous wave's
+    harvest."""
     api, s = mk_sched(n_pods=800)
     s.run_until_drained(max_batch=128)
     ev = flight.snapshot()
@@ -128,26 +130,21 @@ def test_perfetto_export_lanes_and_overlap(flight, tmp_path):
     assert loaded["traceEvents"]
     lanes = {m["args"]["name"] for m in loaded["traceEvents"]
              if m.get("ph") == "M" and m["name"] == "thread_name"}
-    # fastlane joined the fixed lane set in r19 (ISSUE 17)
-    assert lanes == {"host", "device", "fence", "preempt", "fastlane"}
-    tids = {"host": None, "device": None}
-    for m in trace["traceEvents"]:
-        if m.get("ph") == "M" and m["name"] == "thread_name" \
-                and m["args"]["name"] in tids:
-            tids[m["args"]["name"]] = m["tid"]
+    assert lanes == {"host", "fence", "preempt", "fastlane"}
     spans = [m for m in trace["traceEvents"] if m.get("ph") == "X"]
-    host = [m for m in spans if m["tid"] == tids["host"]]
-    device = [m for m in spans if m["tid"] == tids["device"]]
-    assert host and device
-    # overlap: some host bind-flush lies inside a LATER wave's device span
-    flushes = [m for m in host if m["name"].startswith("bind-flush")]
-    overlapped = any(
-        d["ts"] <= f["ts"] and f["ts"] + f["dur"] <= d["ts"] + d["dur"]
-        and d["name"] != f"device-eval {f['name'].split()[-1]}"
-        for f in flushes for d in device)
-    assert overlapped, (flushes, device)
-    # and the quantitative form agrees
-    assert perfetto.overlap_seconds(ev) > 0
+    assert spans and all(m["tid"] == perfetto.TID_HOST for m in spans)
+    start = {}
+    for m in spans:
+        stage, _, wave = m["name"].rpartition(" ")
+        start.setdefault(wave, {})[stage] = m["ts"]
+    waves = sorted(start, key=lambda w: int(w[1:]))
+    assert len(waves) >= 3
+    for w in waves:
+        st = start[w]
+        assert st["dispatch"] < st["harvest"] < st["bind-flush"], (w, st)
+    # overlap: wave k+1 was dispatched before wave k was harvested
+    assert any(start[b]["dispatch"] < start[a]["harvest"]
+               for a, b in zip(waves, waves[1:])), start
 
 
 def test_perfetto_fence_lane_markers(flight, tmp_path):
@@ -216,73 +213,6 @@ def test_stream_gauges_registered_on_scheduler_registry():
     loop2.close()  # superseded registration stays loop3's
     assert s.telemetry.snapshot()["gauge.stream_budget_ms"] == 500.0
     loop3.close()
-
-
-def test_overlap_seconds_matches_pairwise_reference():
-    """The O(n log n) union/prefix form must agree with the brute-force
-    all-pairs intersection on a randomized event soup (and stay fast on
-    a big ring — the full-ring export case)."""
-    rng = np.random.default_rng(5)
-    events = []
-    t = 0.0
-    for w in range(400):
-        t += float(rng.uniform(0.001, 0.01))
-        d_dur = float(rng.uniform(0.001, 0.02))
-        events.append({"kind": "dispatch", "wave": w, "t": t,
-                       "dur": d_dur, "a": 1, "b": 0})
-        h0 = t + d_dur + float(rng.uniform(0.0, 0.01))
-        b_dur = float(rng.uniform(0.001, 0.03))
-        events.append({"kind": "harvest", "wave": w, "t": h0,
-                       "dur": b_dur, "a": 1, "b": 0})
-        events.append({"kind": "bind_flush", "wave": w,
-                       "t": h0 + float(rng.uniform(-0.01, 0.01)),
-                       "dur": float(rng.uniform(0.001, 0.02)),
-                       "a": 1, "b": 0})
-
-    def brute(evs):
-        device, hostspans, dend = [], [], {}
-        for e in evs:
-            if e["kind"] == "dispatch":
-                dend[e["wave"]] = e["t"] + e["dur"]
-                hostspans.append((e["t"], e["t"] + e["dur"], e["wave"]))
-            elif e["kind"] == "harvest":
-                device.append((dend.get(e["wave"], e["t"]),
-                               e["t"] + e["dur"], e["wave"]))
-            elif e["kind"] == "bind_flush":
-                hostspans.append((e["t"], e["t"] + e["dur"], e["wave"]))
-        total = 0.0
-        for h0, h1, hw in hostspans:
-            cov = 0.0
-            for d0, d1, dw in device:
-                if dw == hw:
-                    continue
-                lo, hi = max(h0, d0), min(h1, d1)
-                if hi > lo:
-                    cov += hi - lo
-            total += min(cov, h1 - h0)
-        return total
-
-    got = perfetto.overlap_seconds(events)
-    ref = brute(events)
-    # union-minus-own undercounts only where device windows of different
-    # waves overlap each other (one batch owns the device at a time in
-    # the real engine); on this soup windows DO overlap, so allow the
-    # conservative side only
-    assert got <= ref + 1e-9
-    assert got >= 0.5 * ref  # and it is the same quantity, not garbage
-    # non-overlapping device windows (the real engine's shape): exact
-    seq = []
-    t = 0.0
-    for w in range(50):
-        seq.append({"kind": "dispatch", "wave": w, "t": t, "dur": 0.002,
-                    "a": 1, "b": 0})
-        seq.append({"kind": "harvest", "wave": w, "t": t + 0.010,
-                    "dur": 0.001, "a": 1, "b": 0})
-        if w:
-            seq.append({"kind": "bind_flush", "wave": w - 1,
-                        "t": t + 0.004, "dur": 0.003, "a": 1, "b": 0})
-        t += 0.012
-    assert perfetto.overlap_seconds(seq) == pytest.approx(brute(seq))
 
 
 # ------------------------------------------------------- transport parity

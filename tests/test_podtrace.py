@@ -212,7 +212,7 @@ def test_phases_telescope_exactly_on_a_real_drain(tracer):
     # the window aggregate saw every completion
     agg = snap["phases"]
     assert sum(v["count"] for v in agg.values()) >= 400
-    assert {"queue_wait", "dispatch", "device", "bind_flush"} <= set(agg)
+    assert {"queue_wait", "dispatch", "in_flight", "bind_flush"} <= set(agg)
 
 
 def test_fence_requeue_typed_capacity_reason(tracer):
@@ -542,7 +542,7 @@ def test_perfetto_pod_lanes_render_exemplars(tracer):
     assert pod_spans
     names = {e["name"] for e in pod_spans}
     assert names <= set(pt.PHASE_NAMES), names
-    assert {"queue_wait", "device"} <= names
+    assert {"queue_wait", "in_flight"} <= names
 
 
 # ------------------------------------------------------------------ trend
