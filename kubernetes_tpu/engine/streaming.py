@@ -45,14 +45,107 @@ changes WHEN waves run, never what a wave means.
 
 from __future__ import annotations
 
+import gc
+import threading
 import time
+import weakref
 from typing import Callable, Dict, Optional
 
 from kubernetes_tpu.observability import recorder as flightrec
 from kubernetes_tpu.observability.podtrace import TRACER
 from kubernetes_tpu.observability.recorder import RECORDER
 from kubernetes_tpu.ops.predicates import bucket
-from kubernetes_tpu.utils.trace import COUNTERS, Trace
+from kubernetes_tpu.utils.trace import COUNTERS, Trace, begin_annotation
+
+# CPython's cyclic collector, scheduled by the live loop. The default
+# gen-0 threshold (700 net tracked allocations) runs hundreds of young
+# passes inside one 30k-pod burst, each on the loop thread mid-wave;
+# 100,000 leaves room for a few tracked objects per pod of a full
+# 16,384-pod quantum, and gen 1 and gen 2 follow at 50 passes each, so a
+# full pass stays rare. Collection stays enabled: a loop that never
+# idles still collects, only less often.
+GC_THRESHOLDS = (100_000, 50, 50)
+# how long the loop must have popped nothing, with no wave in flight,
+# before it spends the lull on one collection
+GC_IDLE_S = 0.05
+
+
+class _CollectorSchedule:
+    """The collector's schedule while any ScheduleLoop is live, shared by
+    every loop of the process: the first loop to acquire it raises the
+    thresholds to GC_THRESHOLDS, the last to release it puts back exactly
+    the thresholds it found.
+
+    While held, a `gc.callbacks` hook times every collection. The hook
+    runs inside whatever allocation triggered the collection, possibly
+    while that thread holds COUNTERS' lock, so it only adds to a list of
+    its own; `flush()` moves that into COUNTERS from a safe point (each
+    loop step, close): `gc.pause` (count and seconds, like a span) and
+    `gc.collections.gen0/1/2`. While a profiler capture records, each
+    pause is also a `gc.pause` annotation on the host plane, inside the
+    span it stretched."""
+
+    def __init__(self):
+        # a plain lock, not lockcheck's: release() runs inside collections,
+        # where the checker's own bookkeeping lock may already be held
+        self._lock = threading.RLock()
+        self._users = 0
+        self._saved = None
+        self._hook = self._on_gc
+        self._t0 = None
+        self._tm = None
+        self._tally = [0.0, 0, 0, 0]    # pause seconds, gen0, gen1, gen2
+
+    def acquire(self) -> None:
+        with self._lock:
+            if self._users == 0:
+                self._saved = gc.get_threshold()
+                gc.set_threshold(*GC_THRESHOLDS)
+                gc.callbacks.append(self._hook)
+            self._users += 1
+
+    def release(self) -> None:
+        """Also a weakref finalizer of a loop never closed, so it may run
+        inside a collection: it takes no lock but its own."""
+        with self._lock:
+            self._users -= 1
+            if self._users == 0:
+                gc.callbacks.remove(self._hook)
+                gc.set_threshold(*self._saved)
+                self._end_pause()
+
+    def _end_pause(self) -> Optional[float]:
+        t0, self._t0 = self._t0, None
+        tm, self._tm = self._tm, None
+        if tm is not None:
+            tm.__exit__(None, None, None)
+        return None if t0 is None else time.perf_counter() - t0
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            self._tm = begin_annotation("gc.pause")
+            return
+        dur = self._end_pause()
+        if dur is not None:
+            tally = self._tally
+            tally[0] += dur
+            tally[1 + info["generation"]] += 1
+
+    def flush(self) -> None:
+        # the swap allocates nothing between its read and its write, so a
+        # collection lands wholly in the old tally or wholly in the new
+        fresh = [0.0, 0, 0, 0]
+        tally, self._tally = self._tally, fresh
+        if tally[1] or tally[2] or tally[3]:
+            COUNTERS.add_time("gc.pause", tally[0],
+                              n=tally[1] + tally[2] + tally[3])
+            for gen in range(3):
+                if tally[1 + gen]:
+                    COUNTERS.inc(f"gc.collections.gen{gen}", tally[1 + gen])
+
+
+_COLLECTOR = _CollectorSchedule()
 
 
 class ScheduleLoop:
@@ -154,6 +247,13 @@ class ScheduleLoop:
         telemetry = getattr(sched, "telemetry", None)
         if telemetry is not None:
             telemetry.register_gauges("stream", self._gauges)
+        # the collector's schedule (GC_THRESHOLDS) while this loop is
+        # live; close() releases it, and so does the loop's own
+        # collection should it never be closed
+        self._idle_since: Optional[float] = None
+        self._idle_collected = False
+        _COLLECTOR.acquire()
+        self._release_collector = weakref.finalize(self, _COLLECTOR.release)
 
     # ------------------------------------------------------------- state
 
@@ -263,6 +363,26 @@ class ScheduleLoop:
                                     b=self._breach_streak)
         else:
             self._breach_streak = 0
+
+    # --------------------------------------------------------- collector
+
+    def _collect_if_idle(self) -> None:
+        """Called on a step that popped nothing (so no wave is in flight):
+        once the loop has stayed so for GC_IDLE_S, spend the lull on ONE
+        collection, which can delay only an arrival that lands during it.
+        The young generations, unless gen 2 is due: its automatic pass
+        would otherwise land on the next busy step. A process that
+        switched the collector off keeps it off."""
+        now = time.monotonic()
+        if self._idle_since is None:
+            self._idle_since, self._idle_collected = now, False
+            return
+        if self._idle_collected or now - self._idle_since < GC_IDLE_S \
+                or not gc.isenabled():
+            return
+        self._idle_collected = True
+        gc.collect(2 if gc.get_count()[2] >= GC_THRESHOLDS[2] else 1)
+        COUNTERS.inc("gc.idle_collect")
 
     # --------------------------------------------------------- fast lane
 
@@ -418,6 +538,10 @@ class ScheduleLoop:
             self._pending = {}
         if not pods:
             s._idle_gc()
+            self._collect_if_idle()
+        else:
+            self._idle_since = None
+        _COLLECTOR.flush()
         self._note_health(stats)
         if trace is not None and (pods or prev is not None):
             # only steps that did wave work can breach meaningfully; an
@@ -503,8 +627,14 @@ class ScheduleLoop:
 
     def close(self) -> Dict[str, int]:
         """Drain the in-flight wave and detach from the scheduler; returns
-        any stats not yet reported through step()."""
-        self.flush()
+        any stats not yet reported through step(). The collector's
+        thresholds go back to the process's own once the last live loop
+        closes, also when the drain raises."""
+        try:
+            self.flush()
+        finally:
+            self._release_collector()
+            _COLLECTOR.flush()
         out, self._pending = self._pending, {}
         if self.sched._pipeline is self:
             self.sched._pipeline = None

@@ -424,15 +424,18 @@ class SchedulerCache:
     def cleanup_assumed(self) -> List[str]:
         """Expire assumed pods whose bind was never confirmed within TTL
         (cache.go:355 cleanupAssumedPods). Returns expired pod keys."""
-        expired = []
         now = self._now()
         with self._lock:
-            for key, st in list(self._pod_states.items()):
-                if st.assumed and st.binding_finished and st.deadline is not None \
-                        and now >= st.deadline:
-                    self._remove_pod_locked(st.pod)
-                    del self._pod_states[key]
-                    expired.append(key)
+            # walked in place, the removals after: a copy of the items
+            # would be one new tuple per cached pod (150k at 5k nodes), all
+            # in the collector's young generation at once, on every empty
+            # loop step
+            states = self._pod_states
+            expired = [key for key, st in states.items()
+                       if st.assumed and st.binding_finished
+                       and st.deadline is not None and now >= st.deadline]
+            for key in expired:
+                self._remove_pod_locked(states.pop(key).pod)
         return expired
 
     # -------------------------------------------------------------- internal

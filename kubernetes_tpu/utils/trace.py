@@ -40,9 +40,9 @@ class SpanCounters:
         with self._lock:
             self._counts[name] = self._counts.get(name, 0) + n
 
-    def add_time(self, name: str, seconds: float) -> None:
+    def add_time(self, name: str, seconds: float, n: int = 1) -> None:
         with self._lock:
-            self._counts[name] = self._counts.get(name, 0) + 1
+            self._counts[name] = self._counts.get(name, 0) + n
             self._times[name] = self._times.get(name, 0.0) + seconds
 
     def count(self, name: str) -> int:
@@ -82,6 +82,16 @@ def _load_annotation():
         from jax.profiler import TraceAnnotation
         _annotation = TraceAnnotation
     return _annotation
+
+
+def begin_annotation(name: str):
+    """A profiler annotation of `name`, begun now, while a capture
+    records; None otherwise. End it with `.__exit__(None, None, None)`:
+    for an interval no `with` block can enclose (a collector pause)."""
+    ann = _annotation or _load_annotation()
+    if ann is None or not ann.is_enabled():
+        return None
+    return ann(name)
 
 
 class timed_span:

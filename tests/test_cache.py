@@ -69,6 +69,33 @@ def test_add_confirms_assumed():
     assert cache.node_infos()["n1"].requested.milli_cpu == 1000
 
 
+def test_cleanup_assumed_walks_the_states_in_place():
+    """The expiry walk copies nothing per cached pod (a copy of the items
+    was one young tuple per pod, on every empty loop step), and still
+    expires exactly the assumed pods past their deadline."""
+    import tracemalloc
+    cache, clock = make_cache(ttl=30.0)
+    for i in range(20_000):
+        pod = make_pod(f"c{i}", cpu=1)
+        pod.node_name = "n1"
+        cache.add_pod(pod)
+    late = make_pod("late", cpu=1000)
+    late.node_name = "n2"
+    cache.assume_pod(late)
+    cache.finish_binding(late)
+    clock.t = 31.0
+    tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        expired = cache.cleanup_assumed()
+        peak = tracemalloc.get_traced_memory()[1] - base
+    finally:
+        tracemalloc.stop()
+    assert expired == ["default/late"]
+    assert cache.pod_count() == 20_000
+    assert peak < 64 * 1024, peak      # the copy read 1.28 MB
+
+
 def test_add_moves_pod_when_bound_elsewhere():
     cache, _ = make_cache()
     pod = make_pod("p1", cpu=1000)
