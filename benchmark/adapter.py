@@ -11,9 +11,44 @@ from spec import NodeSpec, PodSpec, Term
 
 
 def program_nodes(nodes: List[NodeSpec]):
-    from kubernetes_tpu.api.types import make_node
+    from kubernetes_tpu.api.types import Taint, TaintEffect, make_node
     return [make_node(n.name, cpu=n.cpu_m, memory=n.mem, pods=n.pods,
-                      labels=dict(n.labels)) for n in nodes]
+                      labels=dict(n.labels),
+                      taints=[Taint(t.key, t.value, TaintEffect(t.effect))
+                              for t in n.taints],
+                      extended=dict(n.extended))
+            for n in nodes]
+
+
+def _term(t: Term):
+    from kubernetes_tpu.api.types import LabelSelector, PodAffinityTerm
+    return PodAffinityTerm(
+        label_selector=LabelSelector(match_labels=dict(t.match)),
+        namespaces=list(t.namespaces), topology_key=t.key)
+
+
+def _affinity(s: PodSpec):
+    from kubernetes_tpu.api.types import Affinity, PodAffinity
+    if not s.terms and not s.preferred:
+        return None
+    sides = []
+    for anti in (False, True):
+        req = [_term(t) for t in s.terms if t.anti == anti]
+        pref = [(w, _term(t)) for w, t in s.preferred if t.anti == anti]
+        sides.append(PodAffinity(required_terms=req, preferred_terms=pref)
+                     if req or pref else None)
+    return Affinity(pod_affinity=sides[0], pod_anti_affinity=sides[1])
+
+
+def _tolerations(s: PodSpec):
+    from kubernetes_tpu.api.types import (
+        TaintEffect,
+        Toleration,
+        TolerationOperator,
+    )
+    return [Toleration(t.key, TolerationOperator(t.operator), t.value,
+                       TaintEffect(t.effect) if t.effect else None)
+            for t in s.tolerations]
 
 
 class PodFactory:
@@ -23,39 +58,16 @@ class PodFactory:
 
     def __init__(self):
         self._templates: Dict[Tuple, object] = {}
-        self._affinity: Dict[Tuple[Term, ...], object] = {}
-
-    def _affinity_of(self, terms: Tuple[Term, ...]):
-        got = self._affinity.get(terms)
-        if got is None:
-            from kubernetes_tpu.api.types import (
-                Affinity,
-                LabelSelector,
-                PodAffinity,
-                PodAffinityTerm,
-            )
-            aff, anti = [], []
-            for t in terms:
-                (anti if t.anti else aff).append(PodAffinityTerm(
-                    label_selector=LabelSelector(match_labels=dict(t.match)),
-                    namespaces=[], topology_key=t.key))
-            got = Affinity(
-                pod_affinity=PodAffinity(required_terms=aff) if aff else None,
-                pod_anti_affinity=(PodAffinity(required_terms=anti)
-                                   if anti else None))
-            self._affinity[terms] = got
-        return got
 
     def pod(self, s: PodSpec, node_name: str = ""):
         from kubernetes_tpu.api.types import make_pod
-        shape = (s.namespace, s.cpu_m, s.mem, s.terms)
-        t = self._templates.get(shape)
+        t = self._templates.get(s.shape)
         if t is None:
             t = make_pod("template", namespace=s.namespace, cpu=s.cpu_m,
-                         memory=s.mem)
-            if s.terms:
-                t.affinity = self._affinity_of(s.terms)
-            self._templates[shape] = t
+                         memory=s.mem, extended=dict(s.extended),
+                         node_selector=dict(s.node_selector),
+                         tolerations=_tolerations(s), affinity=_affinity(s))
+            self._templates[s.shape] = t
         p = copy.copy(t)
         p.name = s.name
         p.uid = s.key

@@ -420,23 +420,31 @@ class _ProgramStream:
 
 def _warm_stream(loop, api, cfg, traffic, fac, seed: int) -> None:
     """Compile every wave shape the window can use: one wave at each size
-    of the loop's quantum ladder, of the cell's own mix and app names, and
-    one of 1.4 times each size below the top (a partial wave: the same
-    padded shape, but the strict tail's own sizes fall between the
-    ladder's); then one whole burst of the traffic as the
-    window sends it, in a seeded order (the quantum's walk and the class
-    counts of shuffled waves with it). The warm pods are deleted so the
-    window starts from the set-up state."""
-    for s in _ladder(loop.min_quantum, loop.max_quantum):
-        _warm_batch(loop, api, fac, spec.mix_pods(cfg, s, f"warm{s}-", ""),
+    of the loop's quantum ladder up to the first that holds a whole
+    burst, of the cell's own mix and app names, and one of 1.4 times each
+    size below the top (a partial wave: the same padded shape, but the
+    strict tail's own sizes fall between the ladder's); then one whole
+    burst of the traffic as the window sends it, in a seeded order (the
+    quantum's walk and the class counts of shuffled waves with it). No
+    warm wave holds more pods than a burst: a pop pads to the next ladder
+    size, and a mix whose pods cannot all be placed at once never drains.
+    The warm pods are deleted so the window starts from the set-up
+    state."""
+    burst = int(traffic["arrivals"]["pods"])
+    ladder = _ladder(loop.min_quantum, loop.max_quantum)
+    top = next((s for s in ladder if s >= burst), ladder[-1])
+    for s in ladder:
+        if s > top:
+            break
+        _warm_batch(loop, api, fac,
+                    spec.mix_pods(cfg, min(s, burst), f"warm{s}-"),
                     quantum=s)
-        if 2 * s <= loop.max_quantum:
-            m = int(1.4 * s)
+        m = int(1.4 * s)
+        if 2 * s <= top and m <= burst:
             _warm_batch(loop, api, fac,
-                        spec.mix_pods(cfg, m, f"warm{m}-", ""),
+                        spec.mix_pods(cfg, m, f"warm{m}-"),
                         quantum=2 * s)
-    pods = spec.mix_pods(cfg, int(traffic["arrivals"]["pods"]),
-                         "rehearsal-", "")
+    pods = spec.mix_pods(cfg, burst, "rehearsal-")
     order = spec.rng_for(seed, "rehearsal").permutation(len(pods))
     _warm_batch(loop, api, fac, [pods[int(i)] for i in order],
                 quantum=loop.min_quantum)
@@ -637,7 +645,7 @@ def _audit_backend(backend, api) -> int:
 def _extender_pool(cfg: dict, seed: int, n: int = 60_000
                    ) -> List[spec.PodSpec]:
     """The closed loop's pods: the mix, each run of 100 in seeded order."""
-    pods = spec.mix_pods(cfg, n, "x-", "")
+    pods = spec.mix_pods(cfg, n, "x-")
     rng = spec.rng_for(seed, "extender-order")
     out = []
     for b in range(0, n, 100):
@@ -653,9 +661,9 @@ def _warm_extender(client, api, backend, cfg, fac, serde) -> None:
     topology pairs; then each class once more through /filter and
     /prioritize, so the window's pods find the device path compiled."""
     seen, warm = set(), []
-    for s in spec.mix_pods(cfg, 200, "warm-", ""):
-        if (s.cpu_m, s.mem, s.labels, s.terms) not in seen:
-            seen.add((s.cpu_m, s.mem, s.labels, s.terms))
+    for s in spec.mix_pods(cfg, 200, "warm-"):
+        if (s.shape, s.labels) not in seen:
+            seen.add((s.shape, s.labels))
             warm.append(s)
     for s in warm:
         pod = fac.pod(s)

@@ -47,6 +47,26 @@ TINY_SECONDS = {"stream": 4.0, "extender": 2.0}
 
 
 AFFINITY = os.path.join(FIX, "affinity_mix.json")
+# cells of fixture configurations in the tiny root: (cell, config file,
+# traffic, limits). `affinity-burst` keeps the older form's kinds under
+# test. `schedperf-anti` and `schedperf-pref` are scheduler_perf's
+# anti-affinity and preferred-affinity templates as published (one label
+# shared by set-up and measured pods, both namespaces in the term, a
+# set-up count over the cluster); `gpu-taints` has node groups with
+# taints and GPUs, and pods with tolerations, GPU requests and a
+# nodeSelector. Through the extender every sampled node score is
+# compared exactly; a stream cell judges placement, which under
+# preferred terms a wave places against its start state (PERF.md
+# section 7), so preferred terms go through the extender only.
+FIXTURE_CELLS = [
+    ("affinity-burst", "affinity_mix.json", "burst30k",
+     {"score_gap_max": 6}),
+    ("schedperf-anti", "schedperf_anti.json", "burst30",
+     {"score_gap_max": 6}),
+    ("gpu-taints", "gpu_taints.json", "burst30k", {"score_gap_max": 6}),
+    ("schedperf-pref-extender", "schedperf_pref.json", "extender-all", {}),
+    ("gpu-taints-extender", "gpu_taints.json", "extender-all", {}),
+]
 
 
 @pytest.fixture(autouse=True)
@@ -66,8 +86,9 @@ def small_quanta(monkeypatch):
 def _tiny_root(tmp_path) -> str:
     """A copy of the benchmark whose configs and mixes are cut to a few
     seconds of CPU: 60 nodes, 300-pod bursts every 2 s; plus a cell of
-    the affinity fixture's mix, so the generator's and the reference's
-    affinity rules run end to end."""
+    each fixture configuration (FIXTURE_CELLS), so the generator's and
+    the reference's rules for every field of the schema run end to
+    end."""
     root = str(tmp_path)
     shutil.copytree(BENCH, os.path.join(root, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
@@ -87,21 +108,32 @@ def _tiny_root(tmp_path) -> str:
             t["arrivals"]["pods"], t["arrivals"]["period_s"] = 300, 2.0
         with open(path, "w") as f:
             json.dump(t, f)
+    with open(os.path.join(tdir, "burst30.json"), "w") as f:
+        json.dump({"driver": "stream", "arrivals": {
+            "kind": "burst", "pods": 30, "period_s": 2.0, "first_s": 0.0},
+            "grace_s": 60, "score_sample": 256}, f)
+    ext = spec.load_json("traffic", "extender")
+    with open(os.path.join(tdir, "extender-all.json"), "w") as f:
+        json.dump(dict(ext, sample_every=1), f)
     b = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    shutil.copy(AFFINITY, os.path.join(root, "benchmark", "configs",
-                                       "affinity-mix.json"))
-    b["configs"].append({"name": "affinity-mix", "source": "fixture",
-                         "file": "benchmark/configs/affinity-mix.json",
-                         "reduced": [], "why": "t"})
-    b["workloads"].append({"name": "affinity-burst", "config":
-                           "affinity-mix", "traffic": "burst30k",
-                           "chips": 1, "why": "t"})
-    for m in b["end_to_end"] + b["per_layer"]:
-        if "density30-burst30k" in m.get("workloads", ()):
-            m["workloads"].append("affinity-burst")
-    with open(os.path.join(root, "benchmark", "limits",
-                           "affinity-burst.json"), "w") as f:
-        json.dump({"score_gap_max": 6}, f)
+    for cell, cfile, traffic, lim in FIXTURE_CELLS:
+        name = json.load(open(os.path.join(FIX, cfile)))["name"]
+        if name not in {c["name"] for c in b["configs"]}:
+            shutil.copy(os.path.join(FIX, cfile), os.path.join(
+                root, "benchmark", "configs", name + ".json"))
+            b["configs"].append({"name": name, "source": "fixture",
+                                 "file": f"benchmark/configs/{name}.json",
+                                 "reduced": [], "why": "t"})
+        b["workloads"].append({"name": cell, "config": name,
+                               "traffic": traffic, "chips": 1, "why": "t"})
+        like = "density30-extender" if traffic.startswith("extender") \
+            else "density30-burst30k"
+        for m in b["end_to_end"] + b["per_layer"]:
+            if like in m.get("workloads", ()):
+                m["workloads"].append(cell)
+        with open(os.path.join(root, "benchmark", "limits",
+                               cell + ".json"), "w") as f:
+            json.dump(lim, f)
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(b, f)
     return root
@@ -303,15 +335,19 @@ def test_peaks_table():
 
 @pytest.mark.parametrize("path", [os.path.join(REPO, c["file"])
                                   for c in BENCHMARK["configs"]]
-                         + [AFFINITY])
+                         + sorted({os.path.join(FIX, c[1])
+                                   for c in FIXTURE_CELLS}))
 def test_background_keeps_the_rules(path):
     cfg = json.load(open(path))
     nodes = spec.make_nodes(cfg)
     placed = spec.background(cfg, nodes, 2 ** 40 + 7)
-    assert len(placed) == cfg["background_per_node"] * len(nodes)
+    setup = spec.deployment(cfg)["setup"]
+    per = setup.get("per_node")
+    assert len(placed) == (per * len(nodes) if per else setup["count"])
     st = ClusterState(nodes)
     assert place_background(st, placed, check=True) == 0
-    assert (st.count == cfg["background_per_node"]).all()
+    if per:
+        assert (st.count == per).all()
 
 
 def test_seeds_change_order_not_load():
@@ -328,7 +364,7 @@ def test_seeds_change_order_not_load():
 # ------------------------------------------------------------ end to end
 
 
-@pytest.mark.parametrize("cell", CELLS + ["affinity-burst"])
+@pytest.mark.parametrize("cell", CELLS + [c[0] for c in FIXTURE_CELLS])
 def test_tiny_run_is_correct(tiny_root, cell, capsys):
     rc, res = _run(tiny_root, cell, 2 ** 35 + 11, 0, capsys)
     assert rc == 0
@@ -392,6 +428,28 @@ def _altered_bind(mp):
     mp.setattr(ApiServerLite, "bind_pods_bulk", alter)
 
 
+def _rebind_one(node: str, tolerating: bool):
+    """The first pod of each bulk bind that has tolerations (or, with
+    `tolerating` false, none) is written to `node` instead."""
+    def fault(mp):
+        from kubernetes_tpu.server.apiserver_lite import ApiServerLite
+        orig = ApiServerLite.bind_pods_bulk
+
+        def alter(self, pods):
+            import copy
+            out, done = [], False
+            for p in pods:
+                if not done and bool(p.tolerations) == tolerating:
+                    p = copy.copy(p)
+                    p.node_name = node
+                    done = True
+                out.append(p)
+            return orig(self, out)
+        mp.setattr(ApiServerLite, "bind_pods_bulk", alter)
+    fault.__name__ = f"rebind_{'tolerating' if tolerating else 'plain'}"
+    return fault
+
+
 def _altered_score(mp):
     from kubernetes_tpu.server.extender import ExtenderHTTPServer
     orig = ExtenderHTTPServer.handle_prioritize
@@ -426,6 +484,23 @@ def test_broken_path_is_not_correct(tiny_root, cell, fault, capsys,
     rc, res = _run(tiny_root, cell, 5, 0, capsys)
     assert rc == 0
     assert not res["correct"], res["checks"]
+
+
+@pytest.mark.parametrize("cell,fault", [
+    # one-per-host pods piled on one node, across both namespaces
+    ("schedperf-anti", _altered_bind),
+    # a pod that tolerates nothing on the NoSchedule-tainted GPU group
+    ("gpu-taints", _rebind_one("hollow-node-59", tolerating=False)),
+    # a GPU pod on a CPU node: no GPUs there, and its nodeSelector fails
+    ("gpu-taints", _rebind_one("hollow-node-0", tolerating=True)),
+])
+def test_fixture_breach_is_an_infeasible_bind(tiny_root, cell, fault,
+                                              capsys, monkeypatch):
+    fault(monkeypatch)
+    rc, res = _run(tiny_root, cell, 6, 0, capsys)
+    assert rc == 0
+    assert not res["correct"]
+    assert res["checks"]["infeasible_binds"]["value"] > 0, res["checks"]
 
 
 # ------------------------------------------------------------- controls
