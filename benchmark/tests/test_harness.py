@@ -5,14 +5,16 @@
 - every cell in BENCHMARK.json resolves to its config, traffic and
   metric files, and the file keeps the contract's shape;
 - adding a cell, a config, a mix or a metric needs only new files and
-  new entries;
+  new entries; a deployment-form configuration so added at full size is
+  cut like any other (tiny.py) and runs end to end;
 - a run that finds no TPU, or no program, exits non-zero with no result;
 - the arithmetic and the trace reduction, on fixed inputs and a trace
   recorded on the CPU (fixtures/);
 - each cell end to end at a tiny size (the TPU look skipped only here),
   with `correct` true;
-- the timed path broken underneath (state unchanged, half of each batch
-  left out, an answer altered where it is produced): `correct` false;
+- the timed path broken underneath, for every cell by its driver (binds
+  dropped, half of each batch left out, an answer altered where it is
+  produced): `correct` false;
 - the controls (control.py) fail the limits the program passes.
 """
 
@@ -32,41 +34,22 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 sys.path.insert(1, REPO)
+sys.path.insert(2, os.path.join(BENCH, "tests"))
 
 import check  # noqa: E402
 import control  # noqa: E402
 import run  # noqa: E402
 import spec  # noqa: E402
 import stats  # noqa: E402
+import tiny  # noqa: E402
 from reference import ClusterState, place_background  # noqa: E402
+from tiny import (FIXTURE_CELLS, SEED_BREACH, SEED_CONTROL,  # noqa: E402
+                  SEED_FAULT, SEED_RUN, SEED_TRACED, TINY_SECONDS)
 
 FIX = os.path.join(BENCH, "tests", "fixtures")
 BENCHMARK = run.load_benchmark(REPO)
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
-TINY_SECONDS = {"stream": 4.0, "extender": 2.0}
-
-
 AFFINITY = os.path.join(FIX, "affinity_mix.json")
-# cells of fixture configurations in the tiny root: (cell, config file,
-# traffic, limits). `affinity-burst` keeps the older form's kinds under
-# test. `schedperf-anti` and `schedperf-pref` are scheduler_perf's
-# anti-affinity and preferred-affinity templates as published (one label
-# shared by set-up and measured pods, both namespaces in the term, a
-# set-up count over the cluster); `gpu-taints` has node groups with
-# taints and GPUs, and pods with tolerations, GPU requests and a
-# nodeSelector. Through the extender every sampled node score is
-# compared exactly; a stream cell judges placement, which under
-# preferred terms a wave places against its start state (PERF.md
-# section 7), so preferred terms go through the extender only.
-FIXTURE_CELLS = [
-    ("affinity-burst", "affinity_mix.json", "burst30k",
-     {"score_gap_max": 6}),
-    ("schedperf-anti", "schedperf_anti.json", "burst30",
-     {"score_gap_max": 6}),
-    ("gpu-taints", "gpu_taints.json", "burst30k", {"score_gap_max": 6}),
-    ("schedperf-pref-extender", "schedperf_pref.json", "extender-all", {}),
-    ("gpu-taints-extender", "gpu_taints.json", "extender-all", {}),
-]
 
 
 @pytest.fixture(autouse=True)
@@ -83,65 +66,9 @@ def small_quanta(monkeypatch):
     monkeypatch.setattr(Scheduler, "stream", stream)
 
 
-def _tiny_root(tmp_path) -> str:
-    """A copy of the benchmark whose configs and mixes are cut to a few
-    seconds of CPU: 60 nodes, 300-pod bursts every 2 s; plus a cell of
-    each fixture configuration (FIXTURE_CELLS), so the generator's and
-    the reference's rules for every field of the schema run end to
-    end."""
-    root = str(tmp_path)
-    shutil.copytree(BENCH, os.path.join(root, "benchmark"),
-                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    for c in BENCHMARK["configs"]:
-        path = os.path.join(root, c["file"])
-        with open(path) as f:
-            cfg = json.load(f)
-        cfg["nodes"]["count"] = 60
-        with open(path, "w") as f:
-            json.dump(cfg, f)
-    tdir = os.path.join(root, "benchmark", "traffic")
-    for name in os.listdir(tdir):
-        path = os.path.join(tdir, name)
-        with open(path) as f:
-            t = json.load(f)
-        if t["driver"] == "stream":
-            t["arrivals"]["pods"], t["arrivals"]["period_s"] = 300, 2.0
-        with open(path, "w") as f:
-            json.dump(t, f)
-    with open(os.path.join(tdir, "burst30.json"), "w") as f:
-        json.dump({"driver": "stream", "arrivals": {
-            "kind": "burst", "pods": 30, "period_s": 2.0, "first_s": 0.0},
-            "grace_s": 60, "score_sample": 256}, f)
-    ext = spec.load_json("traffic", "extender")
-    with open(os.path.join(tdir, "extender-all.json"), "w") as f:
-        json.dump(dict(ext, sample_every=1), f)
-    b = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    for cell, cfile, traffic, lim in FIXTURE_CELLS:
-        name = json.load(open(os.path.join(FIX, cfile)))["name"]
-        if name not in {c["name"] for c in b["configs"]}:
-            shutil.copy(os.path.join(FIX, cfile), os.path.join(
-                root, "benchmark", "configs", name + ".json"))
-            b["configs"].append({"name": name, "source": "fixture",
-                                 "file": f"benchmark/configs/{name}.json",
-                                 "reduced": [], "why": "t"})
-        b["workloads"].append({"name": cell, "config": name,
-                               "traffic": traffic, "chips": 1, "why": "t"})
-        like = "density30-extender" if traffic.startswith("extender") \
-            else "density30-burst30k"
-        for m in b["end_to_end"] + b["per_layer"]:
-            if like in m.get("workloads", ()):
-                m["workloads"].append(cell)
-        with open(os.path.join(root, "benchmark", "limits",
-                               cell + ".json"), "w") as f:
-            json.dump(lim, f)
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(b, f)
-    return root
-
-
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
-    return _tiny_root(tmp_path_factory.mktemp("bench"))
+    return tiny.tiny_root(tmp_path_factory.mktemp("bench"))
 
 
 def _run(root, cell, seed, trace, capsys):
@@ -163,7 +90,7 @@ def test_every_cell_resolves(cell):
     r = run.resolve(BENCHMARK, cell)
     assert r["cfg"]["name"] == r["cell"]["config"]
     assert r["traffic"]["driver"] in drivers.DRIVERS
-    assert r["cell"]["chips"] == 1
+    assert r["cell"]["chips"] in (1, 4)
     names = {m["name"] for m in r["e2e"]}
     assert "setup_s" in names and len(names) >= 2
     assert r["per_layer"], "every cell reports a per-layer metric"
@@ -205,7 +132,7 @@ def test_adding_needs_only_new_files(tmp_path):
     """A new config, mix, metric and cell (with its limits): new files
     and new entries in BENCHMARK.json, and no file that was there
     edited."""
-    root = _tiny_root(tmp_path)
+    root = tiny.tiny_root(tmp_path)
     before = {p: open(p, "rb").read() for p in _files(root)}
     cfg = json.load(open(os.path.join(
         root, "benchmark/configs/kubemark5k-density30.json")))
@@ -244,6 +171,140 @@ def test_adding_needs_only_new_files(tmp_path):
     after = {p: open(p, "rb").read() for p in _files(root)}
     changed = [p for p in before if before[p] != after[p]]
     assert changed == [os.path.join(root, "BENCHMARK.json")]
+
+
+# the deployment that test adds goes under names of its own, which no
+# committed cell, configuration or mix takes
+PROBE = "probe-podanti"
+PROBE_CONFIG = "probe-schedperf5k"
+PROBE_MIX = "probe-burst4k"
+
+
+def _full_copy(dst):
+    """The benchmark as committed, at full size, under `dst`."""
+    shutil.copytree(BENCH, dst / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), dst)
+    return dst
+
+
+def _add_probe_cell(root: str) -> None:
+    """scheduler_perf's SchedulingPodAntiAffinity at 5,000 nodes and
+    1,000 init pods, as a deployment-form configuration (the fixture's
+    templates), in 4,000-pod bursts every 6 s, which fill every free
+    node, the tightest fit for the cut: its traffic, limits and
+    entries."""
+    cfg = json.load(open(os.path.join(FIX, "schedperf_anti.json")))
+    cfg.update(name=PROBE_CONFIG, source=(
+        "https://github.com/kubernetes/kubernetes/tree/master/test/"
+        "integration/scheduler_perf"))
+    cfg["node_groups"][0]["count"] = 5000
+    cfg["setup"]["count"] = 1000
+    with open(os.path.join(root, "benchmark/configs",
+                           cfg["name"] + ".json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(root, "benchmark/traffic", PROBE_MIX + ".json"),
+              "w") as f:
+        json.dump({"driver": "stream", "arrivals": {
+            "kind": "burst", "pods": 4000, "period_s": 6.0, "first_s": 0.0},
+            "grace_s": 60, "score_sample": 256}, f)
+    with open(os.path.join(root, "benchmark/limits", PROBE + ".json"),
+              "w") as f:
+        json.dump({"score_gap_max": 7}, f)
+    b = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    b["configs"].append({"name": cfg["name"], "source": cfg["source"],
+                         "file": f"benchmark/configs/{cfg['name']}.json",
+                         "reduced": [], "why": "one replica per host"})
+    b["workloads"].append({"name": PROBE, "config": cfg["name"],
+                           "traffic": PROBE_MIX, "chips": 1, "why": "t"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        if "density30-burst30k" in m.get("workloads", ()):
+            m["workloads"].append(PROBE)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(b, f)
+
+
+def test_adding_a_deployment_needs_only_new_files(tmp_path, capsys,
+                                                  monkeypatch):
+    """A deployment-form configuration at full size, added as
+    new files and entries, is cut by the tiny root like any other: its
+    tiny run is `correct`, and its broken-bind control is not."""
+    full = _full_copy(tmp_path / "full")
+    before = {p: open(p, "rb").read() for p in _files(full)}
+    _add_probe_cell(str(full))
+    after = {p: open(p, "rb").read() for p in _files(full)}
+    changed = [p for p in before if before[p] != after[p]]
+    assert changed == [str(full / "BENCHMARK.json")]
+    root = tiny.tiny_root(tmp_path / "tiny", src=str(full))
+    rc, res = _run(root, PROBE, SEED_RUN, 0, capsys)
+    assert rc == 0
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0
+    _altered_bind(monkeypatch)
+    rc, res = _run(root, PROBE, SEED_FAULT, 0, capsys)
+    assert rc == 0
+    assert not res["correct"]
+    assert res["checks"]["infeasible_binds"]["value"] > 0, res["checks"]
+
+
+def test_tiny_cut_of_each_form():
+    burst = spec.load_json("traffic", "burst30k")
+    ext = spec.load_json("traffic", "extender")
+    # the older form: exactly 60 nodes; 30,000 x 60 / 5,000 = 360 pods,
+    # capped at 300
+    cfg = spec.load_json("configs", "kubemark5k-density30")
+    cut, t = tiny.tiny_cut(cfg, burst)
+    assert cut == dict(cfg, nodes=dict(cfg["nodes"], count=60))
+    assert t == dict(burst, arrivals=dict(burst["arrivals"], pods=300,
+                                          period_s=2.0))
+    assert tiny.tiny_cut(cfg, ext)[1] == ext
+    # node groups keep their proportions, and {count, template} set-up
+    # pods scale with them: 3,000 + 1,000 + 2,000 nodes and 2,000 GPU
+    # pods cut by 1/100
+    gpu = json.load(open(os.path.join(FIX, "gpu_taints.json")))
+    for g in gpu["node_groups"]:
+        g["count"] *= 100
+    gpu["setup"]["count"] *= 100
+    cut, t = tiny.tiny_cut(gpu, burst)
+    assert [g["count"] for g in cut["node_groups"]] == [30, 10, 20]
+    assert cut["setup"] == {"count": 20, "template": "gpu"}
+    assert t["arrivals"]["pods"] == 300
+    # a group too small for its share keeps one node
+    gpu["node_groups"][2]["count"] = 20
+    cut, _t = tiny.tiny_cut(gpu, burst)
+    assert [g["count"] for g in cut["node_groups"]] == [45, 15, 1]
+    # scheduler_perf's 5000Nodes anti-affinity: every free node filled
+    anti = json.load(open(os.path.join(FIX, "schedperf_anti.json")))
+    anti["node_groups"][0]["count"], anti["setup"]["count"] = 5000, 1000
+    four = dict(burst, arrivals=dict(burst["arrivals"], pods=4000))
+    cut, t = tiny.tiny_cut(anti, four)
+    assert (cut["node_groups"][0]["count"], cut["setup"]["count"],
+            t["arrivals"]["pods"]) == (60, 12, 48)
+
+
+def test_tiny_root_cuts_a_shared_mix_per_cell(tmp_path):
+    """Cells whose cut of a mix differs from the mix's own (the fixtures'
+    cut, f = 1) each read theirs as `<mix>.<cell>`."""
+    full = _full_copy(tmp_path / "full")
+    _add_probe_cell(str(full))
+    b = json.load(open(full / "BENCHMARK.json"))
+    b["workloads"].append(dict(b["workloads"][0], name="probe-density30",
+                               traffic=PROBE_MIX))
+    shutil.copy(full / "benchmark/limits/density30-burst30k.json",
+                full / "benchmark/limits/probe-density30.json")
+    with open(full / "BENCHMARK.json", "w") as f:
+        json.dump(b, f)
+    root = tiny.tiny_root(tmp_path / "tiny", src=str(full))
+    tb = run.load_benchmark(root)
+    cut = {w["name"]: (w["traffic"], run.resolve(tb, w["name"], root)[
+        "traffic"]["arrivals"]["pods"]) for w in tb["workloads"]
+        if w["name"] in ("probe-density30", PROBE, "density30-burst30k")}
+    assert cut == {"probe-density30": (PROBE_MIX + ".probe-density30", 48),
+                   PROBE: (PROBE_MIX + "." + PROBE, 48),
+                   "density30-burst30k": ("burst30k", 300)}
+    t = json.load(open(os.path.join(root, "benchmark/traffic",
+                                    PROBE_MIX + ".json")))
+    assert t["arrivals"]["pods"] == 300
 
 
 def _files(root):
@@ -366,7 +427,7 @@ def test_seeds_change_order_not_load():
 
 @pytest.mark.parametrize("cell", CELLS + [c[0] for c in FIXTURE_CELLS])
 def test_tiny_run_is_correct(tiny_root, cell, capsys):
-    rc, res = _run(tiny_root, cell, 2 ** 35 + 11, 0, capsys)
+    rc, res = _run(tiny_root, cell, SEED_RUN, 0, capsys)
     assert rc == 0
     assert res["correct"], res["checks"]
     assert res["failed"] == 0
@@ -379,7 +440,7 @@ def test_tiny_run_is_correct(tiny_root, cell, capsys):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_tiny_traced_run(tiny_root, cell, capsys):
-    rc, res = _run(tiny_root, cell, 3, 1, capsys)
+    rc, res = _run(tiny_root, cell, SEED_TRACED, 1, capsys)
     assert rc == 0 and res["correct"], res["checks"]
     assert res["device"]["window_s"] > 0
     assert "breakdown" in res
@@ -395,12 +456,37 @@ def test_tiny_traced_run(tiny_root, cell, capsys):
 # ------------------------------------------------------------- faults
 
 
+# the store entries the scheduler binds through: the waves' bulk write,
+# and the classic round's Bindings (the loop's fallback)
+BIND_ENTRIES = ("bind_pods_bulk", "bind_many")
+
+
+def _in_window(fault):
+    """A stream cell's fault, planted once its warm-up is over, so the
+    window's path is the one broken: a warm-up drains until every pod
+    is placed, which a fault that loses pods can keep from ever
+    happening."""
+    def plant(mp):
+        import drivers
+        warm = drivers._warm_stream
+
+        def warm_then_break(*a, **kw):
+            warm(*a, **kw)
+            fault(mp)
+        mp.setattr(drivers, "_warm_stream", warm_then_break)
+    plant.__name__ = fault.__name__
+    return plant
+
+
+@_in_window
 def _store_drops_binds(mp):
+    """Every bind acknowledged and dropped, whichever entry it takes."""
     from kubernetes_tpu.server.apiserver_lite import ApiServerLite
-    mp.setattr(ApiServerLite, "bind_pods_bulk",
-               lambda self, pods: [None] * len(pods))
+    for entry in BIND_ENTRIES:
+        mp.setattr(ApiServerLite, entry, lambda self, xs: [None] * len(xs))
 
 
+@_in_window
 def _half_batch(mp):
     from kubernetes_tpu.engine.scheduler_engine import SchedulingEngine
     orig = SchedulingEngine.harvest_waves
@@ -412,42 +498,58 @@ def _half_batch(mp):
     mp.setattr(SchedulingEngine, "harvest_waves", half)
 
 
+@_in_window
 def _altered_bind(mp):
+    """Every fifth bind of each write goes to hollow-node-0, whichever
+    entry it takes (its Pods and Bindings both carry `node_name`)."""
+    import copy
     from kubernetes_tpu.server.apiserver_lite import ApiServerLite
-    orig = ApiServerLite.bind_pods_bulk
+    for entry in BIND_ENTRIES:
+        def alter(self, xs, orig=getattr(ApiServerLite, entry)):
+            out = []
+            for i, x in enumerate(xs):
+                if i % 5 == 0:
+                    x = copy.copy(x)
+                    x.node_name = "hollow-node-0"
+                out.append(x)
+            return orig(self, out)
+        mp.setattr(ApiServerLite, entry, alter)
 
-    def alter(self, pods):
-        import copy
-        out = []
-        for i, p in enumerate(pods):
-            if i % 5 == 0:
-                p = copy.copy(p)
-                p.node_name = "hollow-node-0"
-            out.append(p)
-        return orig(self, out)
-    mp.setattr(ApiServerLite, "bind_pods_bulk", alter)
+
+def _bound_pod(store, binding):
+    """The stored Pod a Binding names, or None."""
+    from kubernetes_tpu.server.apiserver_lite import NotFound
+    try:
+        return store.get("Pod", binding.pod_namespace, binding.pod_name)
+    except NotFound:
+        return None
 
 
 def _rebind_one(node: str, tolerating: bool):
-    """The first pod of each bulk bind that has tolerations (or, with
-    `tolerating` false, none) is written to `node` instead."""
+    """The first pod of each bind write that has tolerations (or, with
+    `tolerating` false, none) is written to `node` instead, whichever
+    entry it takes (a Binding's pod is looked up in the store)."""
     def fault(mp):
+        import copy
         from kubernetes_tpu.server.apiserver_lite import ApiServerLite
-        orig = ApiServerLite.bind_pods_bulk
-
-        def alter(self, pods):
-            import copy
-            out, done = [], False
-            for p in pods:
-                if not done and bool(p.tolerations) == tolerating:
-                    p = copy.copy(p)
-                    p.node_name = node
-                    done = True
-                out.append(p)
-            return orig(self, out)
-        mp.setattr(ApiServerLite, "bind_pods_bulk", alter)
+        pod_of = {"bind_pods_bulk": lambda store, pod: pod,
+                  "bind_many": _bound_pod}
+        for entry in BIND_ENTRIES:
+            def alter(self, xs, orig=getattr(ApiServerLite, entry),
+                      pod_of=pod_of[entry]):
+                out, done = [], False
+                for x in xs:
+                    p = pod_of(self, x)
+                    if not done and p is not None and \
+                            bool(p.tolerations) == tolerating:
+                        x = copy.copy(x)
+                        x.node_name = node
+                        done = True
+                    out.append(x)
+                return orig(self, out)
+            mp.setattr(ApiServerLite, entry, alter)
     fault.__name__ = f"rebind_{'tolerating' if tolerating else 'plain'}"
-    return fault
+    return _in_window(fault)
 
 
 def _altered_score(mp):
@@ -470,18 +572,21 @@ def _extender_binds_lost(mp):
     mp.setattr(churn, "extender_store_binder", binder)
 
 
-@pytest.mark.parametrize("cell,fault", [
-    ("density30-burst30k", _store_drops_binds),
-    ("density30-burst30k", _half_batch),
-    ("density30-burst30k", _altered_bind),
-    ("affinity-burst", _altered_bind),
-    ("density30-extender", _extender_binds_lost),
-    ("density30-extender", _altered_score),
-])
+# the broken paths of each cell, by its driver: every cell of
+# BENCHMARK.json gets its driver's, and the older form's affinity kinds
+# (a fixture cell) a bind altered
+FAULTS = {"stream": (_store_drops_binds, _half_batch, _altered_bind),
+          "extender": (_extender_binds_lost, _altered_score)}
+BROKEN = [(cell, fault) for cell in CELLS for fault in FAULTS[
+    run.resolve(BENCHMARK, cell)["traffic"]["driver"]]]
+BROKEN.append(("affinity-burst", _altered_bind))
+
+
+@pytest.mark.parametrize("cell,fault", BROKEN)
 def test_broken_path_is_not_correct(tiny_root, cell, fault, capsys,
                                     monkeypatch):
     fault(monkeypatch)
-    rc, res = _run(tiny_root, cell, 5, 0, capsys)
+    rc, res = _run(tiny_root, cell, SEED_FAULT, 0, capsys)
     assert rc == 0
     assert not res["correct"], res["checks"]
 
@@ -497,7 +602,7 @@ def test_broken_path_is_not_correct(tiny_root, cell, fault, capsys,
 def test_fixture_breach_is_an_infeasible_bind(tiny_root, cell, fault,
                                               capsys, monkeypatch):
     fault(monkeypatch)
-    rc, res = _run(tiny_root, cell, 6, 0, capsys)
+    rc, res = _run(tiny_root, cell, SEED_BREACH, 0, capsys)
     assert rc == 0
     assert not res["correct"]
     assert res["checks"]["infeasible_binds"]["value"] > 0, res["checks"]
@@ -512,15 +617,17 @@ def test_controls_fail_where_the_program_passes(tiny_root):
     import drivers
     r = run.resolve(run.load_benchmark(tiny_root), "density30-burst30k",
                     tiny_root)
-    ctl = control.stream_control(r["cfg"], r["traffic"], 4, 4.0,
-                                 "first_fit", r["limits"])
+    ctl = control.stream_control(r["cfg"], r["traffic"], SEED_CONTROL,
+                                 TINY_SECONDS["stream"], "first_fit",
+                                 r["limits"])
     assert not check.verdict(ctl)
     assert ctl["score_gap_max"][0] > ctl["score_gap_max"][1]
     r = run.resolve(run.load_benchmark(tiny_root), "density30-extender",
                     tiny_root)
-    rec = drivers.extender(r["cfg"], r["traffic"], 4, 2.0, False)
-    assert check.verdict(check.extender_checks(rec, r["cfg"], r["traffic"],
-                                               4, r["limits"]))
-    ctl = control.extender_control(rec, r["cfg"], r["traffic"], 4,
-                                   r["limits"])
+    rec = drivers.extender(r["cfg"], r["traffic"], SEED_CONTROL,
+                           TINY_SECONDS["extender"], False)
+    assert check.verdict(check.extender_checks(
+        rec, r["cfg"], r["traffic"], SEED_CONTROL, r["limits"]))
+    ctl = control.extender_control(rec, r["cfg"], r["traffic"],
+                                   SEED_CONTROL, r["limits"])
     assert ctl["score_mismatch_nodes"][0] > 0

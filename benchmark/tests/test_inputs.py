@@ -5,7 +5,9 @@ warm-up pods are made from its seed by `spec.py`. A digest of each, for
 both cells and the older form's affinity fixture at three seeds, was
 taken before the configuration schema grew node groups and pod
 templates; the loader must reproduce it byte for byte, so the cells
-measure the same work from the same seed.
+measure the same work from the same seed. The tiny cells of the harness's
+own tests (tiny.py) are pinned the same way, at every seed their runs
+take, to digests of the tiny root that tiny.py replaced.
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests/test_inputs.py -q
 """
@@ -21,9 +23,12 @@ import pytest
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, BENCH)
+sys.path.insert(1, os.path.join(BENCH, "tests"))
 
 import drivers  # noqa: E402
+import run  # noqa: E402
 import spec  # noqa: E402
+import tiny  # noqa: E402
 
 SEEDS = (1, 2 ** 31 + 3, 2 ** 40 + 7)
 WINDOW_S = 48.0
@@ -134,6 +139,58 @@ PINNED = {
 @pytest.mark.parametrize("cell,seed", sorted(PINNED))
 def test_cell_inputs_are_pinned(cell, seed):
     assert _cell_digest(cell, seed) == PINNED[(cell, seed)]
+
+
+def tiny_digest(root: str, cell: str) -> str:
+    """A cell of the harness's tiny root (tiny.py): its traffic, its
+    nodes, and at every seed its tiny runs take, its set-up, and its
+    arrivals over the tiny window or its extender pool."""
+    r = run.resolve(run.load_benchmark(root), cell, root)
+    cfg, traffic = r["cfg"], r["traffic"]
+    d = _Digest()
+    d.add("traffic", [json.dumps(traffic, sort_keys=True)])
+    nodes = spec.make_nodes(cfg)
+    d.add("nodes", map(_node_row, nodes))
+    for seed in tiny.TINY_SEEDS:
+        d.add(f"background{seed}", ([_pod_row(s), i] for s, i in
+                                    spec.background(cfg, nodes, seed)))
+        if traffic["driver"] == "stream":
+            d.add(f"arrivals{seed}", (
+                [a.due, a.group, _pod_row(a.spec)] for a in spec.arrivals(
+                    cfg, traffic, seed, tiny.TINY_SECONDS["stream"])))
+        else:
+            d.add(f"pool{seed}", map(_pod_row,
+                                     drivers._extender_pool(cfg, seed)))
+    return d.h.hexdigest()
+
+
+# taken on the parent commit of tiny.py, from the tiny root it replaced
+TINY_PINNED = {
+    "density30-burst30k":
+        "50eef9813a0bc88a6f569d2df82328d198de0d548d7fcd3a4fd255ea87fa3ceb",
+    "density30-extender":
+        "3a6bf70e9ec5254a29a75138178d576731c2e9e52eb4d61c1c98cde236b495bb",
+    "affinity-burst":
+        "ac8c70b6bac70ccd84aa815ba4603bccd26bbf5435bab03990a0bcdc234ca482",
+    "schedperf-anti":
+        "37d47f793b58968b7918fa0f743806843ae74cecf2adbe7ad3f4dd77efd66dbe",
+    "gpu-taints":
+        "aa92c9f2fc8b5d40d2d341df35613cde13d524c9fc252848f3267e752ba88cd5",
+    "schedperf-pref-extender":
+        "526dcd2b05b1cbd603126a50b63795b41e37b779a52e57d9e8a265e11e9c8e96",
+    "gpu-taints-extender":
+        "94b75bc253a5d15119693be82e435bf7de964f609bb7ca0ac131542642ffd62e",
+}
+
+
+@pytest.fixture(scope="module")
+def tiny_root(tmp_path_factory):
+    return tiny.tiny_root(tmp_path_factory.mktemp("tiny"))
+
+
+@pytest.mark.parametrize("cell", sorted(TINY_PINNED))
+def test_tiny_inputs_are_pinned(tiny_root, cell):
+    assert tiny_digest(tiny_root, cell) == TINY_PINNED[cell]
 
 
 if __name__ == "__main__":
