@@ -19,16 +19,21 @@ ScheduleLoop is the one engine for both shapes:
 
 - STREAMING mode (``budget_s`` set) admits MICRO-WAVES on a latency
   budget instead of fixed chunks: each step pops ``min(ready, quantum)``
-  where the quantum is a power-of-2 admission cap adapted from the
-  observed per-wave pop->bind-complete wall clock. The quantum doubles
-  while full waves finish well under budget (amortizing per-wave fixed
-  costs when the stream runs hot) and halves when a wave's latency
-  crosses the budget (bounding what one wave can make the next arrival
-  wait for). Pops pad to ``bucket(max(n, min_quantum))`` through the
-  engine's ``wave_pad_floor`` machinery, so the compiled-shape set is
-  the log2 ladder between min_quantum and max_quantum — a ragged
-  arrival stream (345, 589, 100, ...) never mints a fresh XLA compile
-  (the GL003 hazard the ladder exists to kill).
+  where the quantum is a power-of-2 admission cap sized from the
+  observed per-wave pop->bind-complete wall clock. At a pop where the
+  ready backlog is larger than the quantum, the loop fits the latency of
+  the current busy period's completed waves as a fixed cost plus a cost
+  per pod, and raises the quantum at once to the largest ladder size
+  whose predicted latency stays within the budget and whose next size
+  down is predicted under half of it (amortizing per-wave fixed costs
+  when a backlog waits, without crawling up one doubling at a time).
+  The quantum halves when the latency EWMA crosses the budget (bounding
+  what one wave can make the next arrival wait for). Pops pad to
+  ``bucket(max(n, min_quantum))`` through the engine's
+  ``wave_pad_floor`` machinery, so the compiled-shape set is the log2
+  ladder between min_quantum and max_quantum — a ragged arrival stream
+  (345, 589, 100, ...) never mints a fresh XLA compile (the GL003
+  hazard the ladder exists to kill).
 
 Between micro-waves only the delta touches the device (the Firmament
 insight, PAPERS.md §Firmament: incremental re-solve over deltas turns a
@@ -45,6 +50,7 @@ changes WHEN waves run, never what a wave means.
 
 from __future__ import annotations
 
+import collections
 import gc
 import threading
 import time
@@ -68,6 +74,10 @@ GC_THRESHOLDS = (100_000, 50, 50)
 # how long the loop must have popped nothing, with no wave in flight,
 # before it spends the lull on one collection
 GC_IDLE_S = 0.05
+# how many of the current busy period's completed waves the admission fit
+# keeps: enough sizes to part a wave's fixed cost from its cost per pod,
+# recent enough to follow that cost as the cluster fills
+WAVE_HISTORY = 8
 
 
 class _CollectorSchedule:
@@ -209,9 +219,10 @@ class ScheduleLoop:
             sched.engine.wave_pad_floor = self.min_quantum
         # latency model (streaming mode): EWMA of per-wave pop ->
         # bind-complete wall clock, the exact span an arriving pod adds
-        # to the next pod's worst case
+        # to the next pod's worst case; and (pods, latency) of the
+        # current busy period's completed waves, which size the quantum
         self._lat_ewma = 0.0
-        self._grow_streak = 0
+        self._busy_waves = collections.deque(maxlen=WAVE_HISTORY)
         # housekeeping under load (ISSUE 8): empty-round gating starved
         # backoff gc + assume-TTL expiry on a saturated stream — run them
         # on a wall-clock cadence regardless of load
@@ -288,14 +299,17 @@ class ScheduleLoop:
     # --------------------------------------------------------- admission
 
     def _observe_wave(self, handle) -> None:
-        """Feed one completed wave into the latency model and adapt the
-        admission quantum (streaming mode only). The observed span is
-        pop -> bind-complete — with the pipeline two deep it covers the
-        residual device wait plus both host tails, which is exactly what
-        the NEXT arrival's create->bound will inherit."""
+        """Feed one completed wave into the latency model (streaming mode
+        only): its pop -> bind-complete latency joins the busy period's
+        waves that size the quantum (``_size_quantum``) and the EWMA,
+        which halves the quantum the moment it crosses the budget. With
+        the pipeline two deep the span covers the residual device wait
+        plus both host tails, which is exactly what the NEXT arrival's
+        create->bound will inherit."""
         if self.budget_s is None:
             return
         lat = time.monotonic() - handle.pop_ts
+        self._busy_waves.append((len(handle.pods), lat))
         a = 0.3
         self._lat_ewma = lat if self._lat_ewma == 0.0 \
             else (1.0 - a) * self._lat_ewma + a * lat
@@ -304,22 +318,55 @@ class ScheduleLoop:
             # one wave's latency crossed the budget: halve what the next
             # admission may make an arrival wait for
             self.quantum //= 2
-            self._grow_streak = 0
             COUNTERS.inc("stream.quantum_shrink")
-        elif len(handle.pods) >= self.quantum \
-                and self._lat_ewma < 0.5 * self.budget_s \
-                and self.quantum < self.max_quantum:
-            # saturated waves finishing well under budget: the stream is
-            # throughput-limited — grow to amortize per-wave fixed costs.
-            # Two consecutive signals, so one lucky wave can't thrash the
-            # quantum (each growth step is a fresh compiled shape).
-            self._grow_streak += 1
-            if self._grow_streak >= 2:
-                self.quantum *= 2
-                self._grow_streak = 0
-                COUNTERS.inc("stream.quantum_grow")
-        else:
-            self._grow_streak = 0
+
+    def _wave_cost(self):
+        """(fixed seconds, seconds per pod) of a wave's latency, fit by
+        least squares over the busy period's waves. Waves all of one size
+        cannot part the two, and a fit whose cost per pod is not positive
+        would call any size free: then each wave's whole latency is
+        charged per pod, and the dearest such rate taken, which
+        overstates the cost of every larger wave."""
+        waves = self._busy_waves
+        mean_n = sum(n for n, _ in waves) / len(waves)
+        mean_lat = sum(lat for _, lat in waves) / len(waves)
+        sxx = sum((n - mean_n) ** 2 for n, _ in waves)
+        if sxx > 0:
+            per_pod = sum((n - mean_n) * (lat - mean_lat)
+                          for n, lat in waves) / sxx
+            if per_pod > 0:
+                return mean_lat - per_pod * mean_n, per_pod
+        return 0.0, max(lat / n for n, lat in waves)
+
+    def _size_quantum(self) -> None:
+        """At a pop (streaming mode): when the ready backlog is larger
+        than the quantum, raise the quantum at once to the largest ladder
+        size whose predicted latency stays within the budget and whose
+        next size down is predicted under half of it (a full wave that
+        ends within half the budget is what earns a doubling). Only
+        ladder sizes, so no new shape compiles; never lower than it
+        stands (the EWMA alone shrinks it).
+
+        Only waves of the current busy period feed the fit: the loop
+        forgets its waves whenever it goes idle (``step``). What a wave
+        cost before the last idle stretch was measured against another
+        backlog, another fill of the cluster and another load on the
+        host, and a loop that carried it over would size its first waves
+        unlike a freshly started loop facing the same backlog. So every
+        busy period starts from its own measured waves: the first pops
+        go at the quantum as it stands, until a wave has completed."""
+        if not self._busy_waves \
+                or self.sched.queue.ready_count() <= self.quantum:
+            return
+        fixed, per_pod = self._wave_cost()
+        best, size = self.min_quantum, 2 * self.min_quantum
+        while size <= self.max_quantum \
+                and fixed + per_pod * size <= self.budget_s \
+                and fixed + per_pod * (size // 2) < 0.5 * self.budget_s:
+            best, size = size, 2 * size
+        if best > self.quantum:
+            self.quantum = best
+            COUNTERS.inc("stream.quantum_sized")
 
     # ---------------------------------------------------------- degraded
 
@@ -448,6 +495,7 @@ class ScheduleLoop:
             # assumes grow without bound over a long run
             s._idle_gc()
             self._last_gc = now
+        self._size_quantum()
         pods = s.queue.pop_batch(max_n=self.quantum, wait=wait)
         stats["popped"] = len(pods)
         if trace is not None and pods:
@@ -537,6 +585,9 @@ class ScheduleLoop:
                 stats[k] = stats.get(k, 0) + v
             self._pending = {}
         if not pods:
+            # nothing popped and nothing left in flight: the busy period
+            # is over, and its waves size no later one (_size_quantum)
+            self._busy_waves.clear()
             s._idle_gc()
             self._collect_if_idle()
         else:

@@ -13,8 +13,9 @@ instead of the drain:
   (hinted refresh only), and every fence-accepted assume riding the
   raw-delta fold (snapshot.apply_assume_delta) — the Firmament property
   BENCH_r09 showed the drain-shaped engine did NOT have under arrivals;
-- quantum adaptation: the admission cap doubles only on consecutive
-  saturated under-budget waves, halves the moment latency crosses the
+- quantum adaptation: at a pop whose backlog the admission cap cannot
+  hold, the cap jumps to the ladder size the busy period's own measured
+  waves say the budget allows; it halves the moment latency crosses the
   budget, and never leaves [min_quantum, max_quantum].
 
 Plus the tier-1-fast arrival smoke (ISSUE 7 satellite): a few-second
@@ -26,6 +27,8 @@ without running the full bench.
 from __future__ import annotations
 
 import time
+
+import pytest
 
 from kubernetes_tpu.api.types import make_node, make_pod
 from kubernetes_tpu.engine.scheduler import Scheduler
@@ -153,57 +156,220 @@ def test_stream_delta_only_invariants():
 # ------------------------------------------------------ quantum adaptation
 
 
-class _FakeEngine:
-    wave_pad_floor = 0
-
-
-class _FakeSched:
-    def __init__(self):
-        self.engine = _FakeEngine()
-        self._pipeline = None
-        self.pipeline_chunk = 4096
-
-
 class _FakeHandle:
     def __init__(self, n, latency):
         self.pods = [None] * n
         self.pop_ts = time.monotonic() - latency
 
 
+class _FakeEngine:
+    wave_pad_floor = 0
+
+    def dispatch_waves(self, pods, pop_ts, gangs=None):
+        return _FakeHandle(len(pods), 0.0)
+
+
+class _FakeQueue:
+    """A ready backlog of `backlog` pods; records each pop's cap."""
+
+    def __init__(self, backlog=0):
+        self.backlog = backlog
+        self.caps = []
+
+    def ready_count(self):
+        return self.backlog
+
+    def pop_batch(self, max_n=0, wait=None):
+        self.caps.append(max_n)
+        n = min(max_n, self.backlog)
+        self.backlog -= n
+        return [None] * n
+
+
+class _FakeSched:
+    """Enough of a Scheduler for ScheduleLoop.step(): a wave's harvest
+    lands `latency(pods)` seconds after its pop, so the loop observes
+    each wave one step late, as it does a real one."""
+
+    def __init__(self, backlog=0, latency=None):
+        self.engine = _FakeEngine()
+        self._pipeline = None
+        self.pipeline_chunk = 4096
+        self.queue = _FakeQueue(backlog)
+        self.latency = latency
+
+    def sync(self, wait=0.0):
+        return 0
+
+    def _idle_gc(self):
+        pass
+
+    def _sweep_parked_gangs(self, pods):
+        pass
+
+    def _wave_eligible(self, pods):
+        return True
+
+    def _release_gangs_for_wave(self, pods, stats):
+        return pods, None
+
+    def _complete_wave(self, handle):
+        handle.pop_ts = time.monotonic() - self.latency(len(handle.pods))
+        return {"bound": len(handle.pods)}
+
+
+def _sized():
+    return COUNTERS.snapshot().get("stream.quantum_sized", (0, 0))[0]
+
+
+def _settled(fixed, per_pod, budget, lo, hi):
+    """Where a saturated stream settles under the budget, for waves that
+    cost `fixed + per_pod * pods`: the largest ladder size whose wave ends
+    within the budget and whose next size down ends within half of it."""
+    q = lo
+    while 2 * q <= hi and fixed + per_pod * 2 * q <= budget \
+            and fixed + per_pod * q < 0.5 * budget:
+        q *= 2
+    return q
+
+
+# (fixed s, s per pod): settles at 4,096, at max_quantum, at min_quantum
+COST_MODELS = [(0.03, 3e-5), (0.005, 1e-5), (0.1, 1e-4)]
+
+
+@pytest.mark.parametrize("fixed,per_pod", COST_MODELS)
+def test_fresh_loop_reaches_settled_quantum_by_fifth_pop(fixed, per_pod):
+    """A fresh loop facing a 30,000-pod backlog pops twice at the quantum
+    it starts from (no wave of the busy period has completed), sizes from
+    its first wave, and pops the settled size by its 5th pop: once two
+    wave sizes are measured, the fit parts fixed from per-pod cost."""
+    s = _FakeSched(30_000, lambda n: fixed + per_pod * n)
+    loop = ScheduleLoop(s, budget_s=0.25, min_quantum=256,
+                        max_quantum=16384)
+    want = _settled(fixed, per_pod, 0.25, 256, 16384)
+    before = _sized()
+    while s.queue.backlog:
+        loop.step()
+    caps = s.queue.caps
+    assert caps[:2] == [256, 256]
+    assert caps[4] == want, caps
+    assert max(caps) == want, caps
+    assert (_sized() > before) == (want > 256)
+
+
+def test_sized_jump_never_admits_over_budget():
+    """Whatever the waves cost, a jump lands only on a size whose
+    predicted latency stays within the budget: the per-pod overstatement
+    of one measured wave, and the exact fit, both hold true latency
+    under it; where a fit's fixed cost comes out negative, the next size
+    down under half the budget does not by itself admit a size."""
+    budget = 0.25
+    for fixed in (0.0, 0.01, 0.05, 0.2):
+        for per_pod in (1e-6, 1e-5, 1e-4, 1e-3):
+            s = _FakeSched(30_000, lambda n: fixed + per_pod * n)
+            loop = ScheduleLoop(s, budget_s=budget, min_quantum=256,
+                                max_quantum=16384)
+            while s.queue.backlog:
+                before = _sized()
+                loop.step()
+                if _sized() > before:
+                    assert fixed + per_pod * loop.quantum <= budget, \
+                        (fixed, per_pod, s.queue.caps)
+
+    # fit: -0.1 s fixed, 0.2 ms per pod. 1,024 is predicted 0.105 s, so
+    # 2,048 passes the half-budget test yet is predicted 0.310 s
+    s = _FakeSched(30_000)
+    loop = ScheduleLoop(s, budget_s=budget, min_quantum=256,
+                        max_quantum=16384)
+    loop._observe_wave(_FakeHandle(512, 0.0024))
+    loop._observe_wave(_FakeHandle(1024, 0.1048))
+    loop._size_quantum()
+    assert loop.quantum == 1024
+
+
+def test_waves_before_idle_do_not_size_the_next_busy_period():
+    """The loop forgets its waves when it goes idle: a busy period of
+    1 ms waves, then an idle step, then a 30,000-pod backlog pops at the
+    quantum as it stands until a wave of the NEW period has completed."""
+    s = _FakeSched(600, lambda n: 0.001)
+    loop = ScheduleLoop(s, budget_s=0.25, min_quantum=256,
+                        max_quantum=16384)
+    while s.queue.backlog or not loop.idle:
+        loop.step()
+    assert s.queue.caps[-1] == 256      # the idle step; no backlog fit
+    before = _sized()
+    s.queue.backlog = 30_000
+    loop.step()
+    loop.step()
+    assert s.queue.caps[-2:] == [256, 256] and _sized() == before
+    # the period's own first wave sizes it
+    loop.step()
+    assert s.queue.caps[-1] == 16384 and _sized() == before + 1
+
+
+def test_backlog_within_quantum_leaves_it_unchanged():
+    """A backlog at or below the quantum pops whole at the quantum as it
+    stands, however cheap the measured waves: only a backlog the quantum
+    cannot hold sizes it."""
+    s = _FakeSched()
+    loop = ScheduleLoop(s, budget_s=0.25, min_quantum=256,
+                        max_quantum=16384)
+    loop._observe_wave(_FakeHandle(256, 0.001))
+    loop._observe_wave(_FakeHandle(200, 0.001))
+    for backlog in (0, 100, 256):
+        s.queue.backlog = backlog
+        loop._size_quantum()
+        assert loop.quantum == 256
+    s.queue.backlog = 257
+    loop._size_quantum()
+    assert loop.quantum == 16384
+
+
 def test_quantum_adaptation_bounds_and_hysteresis():
-    """Unit contract of the admission model: grow only after TWO
-    consecutive saturated waves well under budget (one lucky wave must
-    not mint a compiled shape), shrink immediately when the EWMA crosses
-    the budget, clamp to [min_quantum, max_quantum]."""
+    """Unit contract of the admission model: no growth from waves alone,
+    however fast (a partial wave earns nothing: the quantum grows only at
+    a pop whose backlog it cannot hold); shrink immediately when the EWMA
+    crosses the budget; clamp to [min_quantum, max_quantum]; fixed mode
+    inert."""
     s = _FakeSched()
     loop = ScheduleLoop(s, budget_s=0.2, min_quantum=64, max_quantum=256)
     assert loop.quantum == 64
     assert s.engine.wave_pad_floor == 64  # the shape-ladder floor
 
-    # one fast full wave: no growth yet (hysteresis)
-    loop._observe_wave(_FakeHandle(64, 0.01))
+    # fast waves, partial and full, while the backlog fits: no growth
+    for n in (10, 64, 64):
+        loop._observe_wave(_FakeHandle(n, 0.01))
+    s.queue.backlog = 64
+    loop._size_quantum()
     assert loop.quantum == 64
-    # second consecutive: grows
-    loop._observe_wave(_FakeHandle(64, 0.01))
+    # a backlog it cannot hold: one size the per-pod fit cannot part
+    # from the other, so the dearest per-pod rate (the partial wave's,
+    # 1 ms) sizes it: 128 is predicted 0.128 s, 256 0.256 s > budget
+    s.queue.backlog = 1000
+    loop._size_quantum()
     assert loop.quantum == 128
-    # a partial wave resets the streak
-    loop._observe_wave(_FakeHandle(10, 0.01))
-    loop._observe_wave(_FakeHandle(128, 0.01))
-    assert loop.quantum == 128
-    loop._observe_wave(_FakeHandle(128, 0.01))
-    assert loop.quantum == 256
-    # cap: saturated fast waves cannot exceed max_quantum
-    loop._observe_wave(_FakeHandle(256, 0.01))
-    loop._observe_wave(_FakeHandle(256, 0.01))
+    # cap: a cheap wave cannot size a fresh loop past max_quantum
+    loop = ScheduleLoop(s, budget_s=0.2, min_quantum=64, max_quantum=256)
+    loop._observe_wave(_FakeHandle(64, 0.0001))
+    loop._size_quantum()
     assert loop.quantum == 256
     # over-budget wave shrinks immediately...
     loop._observe_wave(_FakeHandle(256, 5.0))
     assert loop.quantum == 128
-    # ...and the floor holds no matter how slow it gets
+    # ...and the floor holds no matter how slow it gets; a backlog does
+    # not size it back up past what its slow waves predict
     loop._observe_wave(_FakeHandle(128, 5.0))
     loop._observe_wave(_FakeHandle(64, 5.0))
     loop._observe_wave(_FakeHandle(64, 5.0))
+    loop._size_quantum()
     assert loop.quantum == 64
+
+    # fixed mode: neither a slow wave nor a backlog moves the chunk
+    s = _FakeSched(30_000)
+    loop = ScheduleLoop(s, chunk=1000)
+    loop._observe_wave(_FakeHandle(1000, 9.9))
+    loop._size_quantum()
+    assert loop.quantum == 1000
 
 
 def test_fixed_mode_pins_one_shape():
