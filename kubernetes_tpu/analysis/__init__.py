@@ -25,8 +25,7 @@ Two halves:
   loudly at test time instead of corrupting a blind wave.
 
 tests/test_graftlint.py pins the clean-tree gate (tier-1) and per-rule
-fixtures; bench.py --lint-gate refuses to report perf numbers from a
-tree with unsuppressed hazards.
+fixtures, so no tree with unsuppressed hazards passes.
 """
 
 from kubernetes_tpu.analysis.lint import (  # noqa: F401

@@ -1,8 +1,8 @@
 """graftlint engine: file discovery, two-pass analysis, pragmas, baseline.
 
 Pure stdlib + AST — importing this module never imports jax/numpy, so the
-tier-1 clean-tree gate and `bench.py --lint-gate` cost milliseconds and
-run identically on a box with no accelerator.
+tier-1 clean-tree gate costs milliseconds and runs identically on a box
+with no accelerator.
 
 Pass 1 builds the cross-file ProjectIndex (which bare names are jitted
 callables anywhere in the set — GL002's taint sources and GL003's

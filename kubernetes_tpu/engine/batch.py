@@ -156,8 +156,7 @@ def _batch_pre(pods: Arrays, nodes: Arrays,
     P pods over C << P classes repeats each class row P/C times and the
     label-axis matmuls in here (selector_fit, node_affinity_counts) scale
     with the cluster once hostname domains are interned: computing them
-    per POD was the dominant hidden cost of the r08 affinity tail
-    (PROFILE_r08.md §3)."""
+    per POD was the dominant hidden cost of the affinity tail."""
     static_fit = preds.static_fits(pods, nodes) \
         & preds.node_condition_fit(pods, nodes)
     tt_cnt = jnp.einsum("pt,nt->pn", pods["intolerated_pref"],

@@ -149,20 +149,12 @@ class Scheduler:
         # the targeted changed_hint path instead of walking all N nodes
         self.engine.track_dirty = True
         self.queue = SchedulingQueue(now=now)
-        # pipelined drain knobs (run_until_drained/run_arrival): chunk =
-        # pods per wave (double-buffered), set by PIPELINE_CHUNK-style
-        # callers; _pipeline is the live pipeline whose in-flight wave a
-        # capacity-unsafe watch event must flush before applying
+        # pipelined drain knobs (run_until_drained/stream): chunk =
+        # pods per wave (double-buffered), set by callers; _pipeline is
+        # the live pipeline whose in-flight wave a capacity-unsafe watch
+        # event must flush before applying
         self.pipeline_chunk = 4096
         self._pipeline = None
-        # gangs ride the pipelined wave path (ISSUE 5): quorum-eligible
-        # gangs dispatch as ordinary wave batches with an all-or-nothing
-        # gang fence at harvest. False restores the r07/r08 behavior —
-        # every gang-bearing chunk flushes the pipeline into the classic
-        # synchronous round — kept reachable as the A/B baseline
-        # (bench.measure_gang_mix flips this attribute for the
-        # gangmix_flush_elapsed_s measurement).
-        self.gang_pipeline = True
         # wave-path preemption (ISSUE 14): with the PodPriority gate on,
         # a harvest's unschedulable preemptors plan displacements against
         # the snapshot's priority bands and commit through the store's
@@ -175,9 +167,6 @@ class Scheduler:
         # max-evictions-per-minute window plus optional per-band floors;
         # denied plans count budget_deferred and wait out their backoff.
         self.disruption_budget = DisruptionBudget(now=now)
-        # bench hook: preempt_observer(commit_monotonic, latency_s,
-        # victim_count) after every committed preemption. None = off.
-        self.preempt_observer = None
         self.metrics = SchedulerMetrics()
         # unified telemetry (ISSUE 13): this scheduler's histograms +
         # counters in the one labeled namespace; a live ScheduleLoop
@@ -186,8 +175,8 @@ class Scheduler:
         self.telemetry.register_metrics("scheduler", self.metrics)
         self.record_events = record_events
         self.events: List[Event] = []
-        # per-wave bind telemetry for loop owners (bench.run_arrival's
-        # honest create->bound accounting): called as
+        # per-wave bind telemetry for loop owners (the benchmark's
+        # drivers time every bind through it): called as
         # wave_observer(bind_done_monotonic, bound_pod_keys) after every
         # successful bulk bind — classic rounds and pipelined harvests
         # alike — so a scenario can join bind instants against its own
@@ -445,7 +434,7 @@ class Scheduler:
         may safely re-apply. One clock read for the whole run: the stamps
         feed the metrics distribution, and sync() runs per wave — finer
         granularity than the sync cadence would be fiction anyway (the
-        bench's honest latency joins against the CREATOR's stamps)."""
+        benchmark's latency joins against the CREATOR's stamps)."""
         if not fresh:
             return
         now = time.monotonic()
@@ -532,7 +521,7 @@ class Scheduler:
         slow-schedule trace (generic_scheduler.go:89-90's 100ms utiltrace).
 
         This is the SYNCHRONOUS round: device placement blocks before the
-        host bookkeeping runs. run_until_drained/run_arrival use the
+        host bookkeeping runs. run_until_drained and the stream loop use the
         pipelined drain (wave k+1's device time overlapping wave k's host
         phases) and fall back to this body per chunk when a batch needs the
         strict/oracle machinery."""
@@ -803,18 +792,6 @@ class Scheduler:
         return count
 
     # ------------------------------------------------------ pipelined drain
-
-    def _wave_eligible(self, pods: List[Pod]) -> bool:
-        """Cheap host-side gate before dispatch: with gang_pipeline off,
-        gang-bearing chunks flush to the classic round (the pre-ISSUE 5
-        behavior, kept as the bench A/B baseline). No chunk SHAPE is
-        host-gated anymore (ISSUE 18): required (anti-)affinity, gangs,
-        host-check, and Policy classes all ride the wave path (ISSUEs
-        3/5/18); the engine returns None only for the gang-quorum-
-        unreachable corner, which the caller flushes per chunk."""
-        if self.gang_pipeline:
-            return True
-        return all(gangmod.gang_name(p) is None for p in pods)
 
     def _release_gangs_for_wave(self, pods: List[Pod], stats: Dict[str, int]
                                 ) -> Tuple[List[Pod], Optional[list]]:
@@ -1116,9 +1093,6 @@ class Scheduler:
                 self._event(pod, "Normal", "TriggeredPreemption",
                             f"{len(plan.victims)} lower-priority pod(s) "
                             f"on {plan.node_name} evicted")
-            if self.preempt_observer is not None:
-                self.preempt_observer(bind_done, bind_done - t_plan,
-                                      len(plan.victims))
             if RECORDER.enabled:
                 RECORDER.record(flightrec.PREEMPT_COMMIT, wave=wave_id,
                                 t0=t_plan, dur=bind_done - t_plan,

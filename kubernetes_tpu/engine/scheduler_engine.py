@@ -874,7 +874,7 @@ class _WaveEncoding:
     column: wave-eligible classes have singleton domains (domain == node),
     and a [C, L] form would drag the label axis — which scales with the
     cluster once hostname keys are interned — through every wave and fence
-    (the PR-start collapse, PROFILE_r08.md). The strict tail gets a
+    (the affinity-tail collapse it once caused). The strict tail gets a
     PROJECTED domain view instead (tail_cols: only columns its classes'
     terms touch). Validity is (vocab_gen, cache.aff_seq) plus — for
     affinity encodings, whose topology views bake label CONTENT —

@@ -1,6 +1,6 @@
 """The always-on incremental scheduler loop (ISSUE 7).
 
-BENCH_r09 exposed the shape of the old engine: a pre-loaded 30k-pod
+An arrival run exposed the shape of the old engine: a pre-loaded 30k-pod
 backlog drained at 28.8k pods/s, but under a live 5k/s offered stream it
 bound almost nothing while pods arrived (backlog 29k at offer end, p99
 create->bound 2.2 s) — a batch drain wearing a streaming costume. A real
@@ -504,9 +504,9 @@ class ScheduleLoop:
         handle = None
         if not pods:
             # parked-gang sweep on empty steps only: a pod-ful step either
-            # takes the wave path (no gang members by eligibility) and
-            # sweeps below, or falls back to _process_batch which runs the
-            # arrival-exempt sweep itself
+            # takes the wave path, whose gang release sweeps, or falls
+            # back to _process_batch which runs the arrival-exempt sweep
+            # itself
             s._sweep_parked_gangs(())
         if pods and self.degraded:
             # degraded mode: churn is beating the blind-wave fence — run
@@ -516,7 +516,7 @@ class ScheduleLoop:
         if pods:
             pop_ts = time.monotonic()
             chunk_pods = pods
-            if not self.degraded and s._wave_eligible(pods):
+            if not self.degraded:
                 # quorum-ready gangs ride the wave path as ordinary
                 # batches (ISSUE 5) — the harvest applies their
                 # all-or-nothing fence; below-quorum members park here
@@ -530,10 +530,9 @@ class ScheduleLoop:
             if handle is None and chunk_pods:
                 # classic fallback (ISSUE 18: no chunk SHAPE lands here
                 # anymore — host-check and Policy chunks ride the wave).
-                # Remaining triggers: gangs with gang_pipeline off, a
-                # gang whose quorum is unreachable from its wave-eligible
-                # members, degraded mode. The counter is the no-flush
-                # routing guard's observable.
+                # Remaining triggers: a gang whose quorum is unreachable
+                # from its wave-eligible members, and degraded mode. The
+                # counter is the no-flush routing guard's observable.
                 COUNTERS.inc("stream.chunk_flush")
                 self.flush()
                 sub = s._process_batch(chunk_pods, pop_ts)
@@ -615,7 +614,7 @@ class ScheduleLoop:
     # ------------------------------------------------------------ quiesce
 
     def settled(self) -> bool:
-        """The ONE quiesce predicate (bench stop conditions, drain(),
+        """The ONE quiesce predicate (benchmark stop conditions, drain(),
         tests): pipeline idle AND watch stream drained AND ready queue
         empty AND backoff heap empty. The deferred check matters: a pod
         requeued after a transient error is RETRIABLE, and declaring the
@@ -631,8 +630,8 @@ class ScheduleLoop:
         """Step until settled; returns accumulated stats. Termination is
         the CALLER's contract — truly-unschedulable pods re-enter the
         ready queue forever, so scenario drivers wrap this in a
-        wall-clock deadline (bench.run_arrival) or feed only placeable
-        pods (warm/prime phases, tests)."""
+        wall-clock deadline or feed only placeable pods (warm/prime
+        phases, tests)."""
         total: Dict[str, int] = {}
         while True:
             stats = self.step()

@@ -390,8 +390,8 @@ def _wave_aff_mask(aff: Arrays, committed: jnp.ndarray) -> jnp.ndarray:
     the seeded strict tail), so domain occupancy IS per-node occupancy —
     the mask never touches the label axis, whose width scales with the
     cluster when hostname keys are interned (a [C, L] form here cost
-    ~100x at 5k nodes; see PROFILE_r08.md). A node n is forbidden for
-    class c when it carries (a) a static forbid (existing pods' matching
+    ~100x at 5k nodes). A node n is forbidden for class c when it
+    carries (a) a static forbid (existing pods' matching
     anti terms — precomputed [C, N] at encoding build), (b) a committed
     pod matching one of c's own required anti terms whose key n has, or
     (c) a committed pod of class d whose anti term matches c (the
@@ -801,8 +801,8 @@ def tail_rounds_loop(cls: Arrays, nodes: Arrays, state: NodeState,
     exact: the affinity occupancy each pod evaluates against, and the
     classic one-at-a-time tie-break order. Only the first is a
     CONSTRAINT; the second is the same tie-spreading freedom every
-    wave-mode class already trades away (PROFILE_r08 §6 — batch-defined
-    RR fan-out instead of the classic serialized order). So each round:
+    wave-mode class already trades away (batch-defined RR fan-out
+    instead of the classic serialized order). So each round:
 
       1. re-evaluates the REQUIRED mask for every class exactly against
          the cumulative occupancy carry (ops/affinity.step_fits_all over

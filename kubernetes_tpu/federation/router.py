@@ -126,7 +126,6 @@ class FederationRouter:
         # per-cell lanes in perfetto.add_process_lanes worker shape
         self._events: Dict[str, List[Dict]] = {
             c.name: [] for c in self.cells}
-        self.admit_spans: List[Tuple[float, float, int]] = []
         self.telemetry = TelemetryRegistry()
         self.telemetry.register_counters(
             "federation", self.counters_snapshot,
@@ -299,11 +298,7 @@ class FederationRouter:
 
     def admit(self, pods: List,
               exclude: Optional[Dict[str, str]] = None) -> Dict[str, int]:
-        """Route + admit one batch; returns per-cell accepted counts.
-        The admission span (route decision + every ADMIT round trip) is
-        recorded per batch — the 'router admission on top of per-cell
-        create->bound' number the bench reads as p99."""
-        t0 = time.monotonic()
+        """Route + admit one batch; returns per-cell accepted counts."""
         assigned, leftover = self.route(pods, exclude=exclude)
         self.backlog.extend(leftover)
         out: Dict[str, int] = {}
@@ -327,9 +322,6 @@ class FederationRouter:
                 self.counters["admitted"] += accepted
                 self.counters["admit_replays"] += replayed
             out[c.name] = accepted
-        if pods:
-            self.admit_spans.append(
-                (t0, time.monotonic() - t0, len(pods)))
         return out
 
     def pump_backlog(self) -> int:
@@ -421,14 +413,6 @@ class FederationRouter:
                      "counts": {"events": len(self._events[c.name])},
                      "events": list(self._events[c.name])}
                     for c in self.cells]
-
-    def admission_p99_ms(self) -> float:
-        """p99 over per-batch admission spans (route + admit wire), ms."""
-        if not self.admit_spans:
-            return 0.0
-        durs = sorted(d for _t, d, _n in self.admit_spans)
-        i = min(len(durs) - 1, int(round(0.99 * (len(durs) - 1))))
-        return durs[i] * 1e3
 
     def close(self) -> None:
         for c in self.cells:

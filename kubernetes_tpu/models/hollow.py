@@ -384,7 +384,7 @@ def gang_mix_pods(n: int, seed: int = 0,
     the pipeline (the pre-ISSUE 5 routing), it drags the stream's
     affinity classes back through the CLASSIC path — per-chunk
     AffinityData rebuilds and the full-label-axis strict scan, the exact
-    costs PROFILE_r08 measured as the PR-start collapse — so "gangs stop
+    costs behind the affinity-tail collapse — so "gangs stop
     flushing" is worth far more than the gangs themselves. Every gang pod
     shares ONE spec class (annotations are identity, not spec —
     state/classes.pod_class_key), so the wave encoding's class axis stays

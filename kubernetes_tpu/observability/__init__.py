@@ -35,9 +35,6 @@ Pod-level black box (ISSUE 15), same host-pure discipline:
   transport (HTTP /debug/pods + /debug/slo, the binary STATS verb,
   VerdictService.debug_snapshot) and fold into every registry
   snapshot.
-- ``trend``: the BENCH_r*.json trajectory reader behind
-  ``bench.py --trend`` (regression flags past the box-noise band,
-  nonzero exit for CI).
 """
 
 from kubernetes_tpu.observability.podtrace import TRACER, PodTracer

@@ -5,7 +5,6 @@
         --nodes 5000 --pods 30000 --profile density
     python -m kubernetes_tpu.observability --events raw.json --last 200
     python -m kubernetes_tpu.observability --vars
-    python -m kubernetes_tpu.observability --trend [--band 0.30]
 
 --trace runs the pipelined drain (warmup pass first so compiles never
 pollute the window), records every wave, and writes the Chrome
@@ -13,18 +12,14 @@ trace-event JSON — load it in chrome://tracing or ui.perfetto.dev to
 see each wave's dispatch, harvest and bind flush; with GRAFT_PODTRACE=1
 the tracer's tail-exemplar pods render as additional per-pod phase
 lanes. --events dumps the raw recorder ring instead; --vars prints a
-telemetry-registry snapshot of the recorded run. --trend renders the
-BENCH_r*.json headline trajectory and exits nonzero on a regression
-past the box-noise band (observability/trend.py — the CI contract;
-pure stdlib, runs without jax). Exit 0 on success, 1 on a trend
-regression, 2 on usage errors.
+telemetry-registry snapshot of the recorded run. Exit 0 on success,
+2 on usage errors.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -78,12 +73,9 @@ def main(argv=None) -> int:
                     help="print a telemetry-registry snapshot of the run")
     ap.add_argument("--last", type=int, default=0,
                     help="bound the exported event tail (0 = all)")
-    ap.add_argument("--nodes", type=int,
-                    default=int(os.environ.get("BENCH_NODES", 5000)))
-    ap.add_argument("--pods", type=int,
-                    default=int(os.environ.get("BENCH_PODS", 30000)))
-    ap.add_argument("--profile",
-                    default=os.environ.get("BENCH_PROFILE", "density"))
+    ap.add_argument("--nodes", type=int, default=5000)
+    ap.add_argument("--pods", type=int, default=30000)
+    ap.add_argument("--profile", default="density")
     ap.add_argument("--chunk", type=int, default=0,
                     help="fixed wave size (0 = auto)")
     ap.add_argument("--no-overlap", action="store_true",
@@ -91,27 +83,11 @@ def main(argv=None) -> int:
     ap.add_argument("--no-warm", action="store_true",
                     help="skip the warmup drain (compiles land in the "
                          "recorded window)")
-    ap.add_argument("--trend", action="store_true",
-                    help="render the BENCH_r*.json headline trend and "
-                         "exit nonzero on a regression (no jax, no "
-                         "drain)")
-    ap.add_argument("--root", default=None,
-                    help="trend: directory holding the artifacts")
-    ap.add_argument("--band", type=float, default=None,
-                    help="trend: relative noise band (default 0.30)")
     args = ap.parse_args(argv)
-    if args.trend:
-        from kubernetes_tpu.observability import trend
-        targv = []
-        if args.root:
-            targv += ["--root", args.root]
-        if args.band is not None:
-            targv += ["--band", str(args.band)]
-        return trend.main(targv)
     if not (args.trace or args.events or args.vars):
         ap.print_usage(sys.stderr)
-        print("nothing to do: pass --trace, --events and/or --vars, "
-              "or --trend", file=sys.stderr)
+        print("nothing to do: pass --trace, --events and/or --vars",
+              file=sys.stderr)
         return 2
 
     events, elapsed, totals, sched = _record_drain(

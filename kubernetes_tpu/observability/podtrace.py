@@ -45,9 +45,7 @@ Cost model (the reason this can stay armed in production):
   bounded three ways: ``max_live`` concurrent timelines (past it, new
   begins are DROPPED and counted — never silent), ``max_events`` per
   timeline (fence-requeue loops cannot grow one pod unboundedly), and
-  a per-window rotation that abandons stale live entries. bench.py
-  measures the total as an interleaved on/off A/B on the arrival
-  headline (podtrace_overhead_pct in the BENCH artifact).
+  a per-window rotation that abandons stale live entries.
 
 Completion feeds three consumers:
 
@@ -555,7 +553,7 @@ class PodTracer:
 
 # process-wide tracer, disabled unless armed — the emit sites all guard
 # on TRACER.enabled (exact no-op off). GRAFT_PODTRACE=1 arms at import;
-# bench.py flips it programmatically for the on/off A/B.
+# tests and callers may flip it programmatically.
 TRACER = PodTracer()
 if os.environ.get("GRAFT_PODTRACE", "0") == "1":
     TRACER.enable()

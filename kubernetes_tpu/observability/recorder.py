@@ -1,11 +1,11 @@
 """FlightRecorder: a bounded, preallocated ring of typed per-wave events.
 
-Every PROFILE_r*.md since r06 has been hand-built attribution — the r13
-churn dip needed a same-box HEAD-vs-PR A/B to blame box contention, the
-r14 8-device p99 swing shipped as an "honesty data point" because
-nothing recorded where a wave's milliseconds went. Borg's operability
-rests on every task self-publishing health for introspection; Sparrow's
-evaluation hinges on per-task latency decomposition (PAPERS.md). The
+Attribution without a recorder is hand-built: a churn dip needs a
+same-box HEAD-vs-PR A/B to blame box contention, and a p99 swing stays
+unexplained because nothing recorded where a wave's milliseconds went.
+Borg's operability rests on every task self-publishing health for
+introspection; Sparrow's evaluation hinges on per-task latency
+decomposition (PAPERS.md). The
 always-on engine gets both built in: the hot paths emit one typed event
 per WAVE (never per pod) into a preallocated ring, and the exporter in
 ``perfetto.py`` renders the ring as a loadable timeline.
@@ -17,9 +17,7 @@ Cost model — the reason this can stay armed in production:
   is read, nothing allocates. Exact no-op.
 - ON: one uncontended lock acquire + six scalar writes into
   preallocated numpy arrays per event, at wave cadence (tens of events
-  per second at the 20k pods/s headline). bench.py measures this as a
-  recorder-on/off A/B on the arrival headline (telemetry_overhead_pct
-  in the BENCH artifact) instead of asserting it.
+  per second at a 20k pods/s stream).
 
 The recorder is HOST-side pure: events carry monotonic timestamps and
 host ints already in hand — it never touches a device value (fetching
@@ -210,7 +208,7 @@ class FlightRecorder:
 # process-wide ring, disabled unless armed: the emit sites in the
 # engine/streaming/bind paths all guard on RECORDER.enabled.
 # GRAFT_FLIGHT_RECORDER=1 arms it at import (the CLI and ad-hoc
-# debugging knob; bench.py flips it programmatically for the A/B).
+# debugging knob; tests and callers may flip it programmatically).
 RECORDER = FlightRecorder()
 if os.environ.get("GRAFT_FLIGHT_RECORDER", "0") == "1":
     RECORDER.enable()

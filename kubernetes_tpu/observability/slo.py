@@ -1,5 +1,5 @@
 """SLO burn-rate monitor (ISSUE 15): the engine notices its own p99
-drifting before a human reads a PROFILE_r*.md.
+drifting before a human reads a profile.
 
 Borg's SLO-driven operation is the model: an always-on service is
 operated against an explicit objective, and the thing that pages is the
@@ -211,7 +211,7 @@ class SLOMonitor:
 
 # process-wide monitor, disabled unless armed (the scheduler's bound
 # paths guard on SLO.enabled — exact no-op off). GRAFT_SLO=1 arms at
-# import; bench.py arms it with the tracer for the podtrace A/B arm.
+# import; callers may arm it programmatically with the tracer.
 SLO = SLOMonitor()
 
 # the fast tier's own objective (ISSUE 17): latency-critical pods are

@@ -1,7 +1,7 @@
 """Single-threaded async event loop speaking the binary fleet framing
 (ISSUE 11) — the transport that kills the thread-per-connection wall.
 
-PROFILE_r12: a NO-OP ThreadingHTTPServer with 100 in-process clients
+A NO-OP ThreadingHTTPServer with 100 in-process clients
 measures ~196 req/s on the 2-core box — ~200 Python threads in GIL
 rotation IS the platform wall, and the fleet saturates it ~25x below the
 service's measured in-process capacity. This server replaces the
@@ -183,7 +183,6 @@ class AsyncBinaryServer:
         async def teardown():
             if self._server is not None:
                 self._server.close()
-                await self._server.wait_closed()
             # resolve anything queued OR on the worker — FILTERs and
             # BINDs — so no ticket leaks into a future nobody will
             # complete (an in-flight bind may still LAND downstream:
@@ -208,10 +207,19 @@ class AsyncBinaryServer:
             # transports and its buffers outlive the server object
             stragglers = [t for t in self._conn_tasks if not t.done()]
             self.cancelled_conn_tasks = len(stragglers)
+            pump = self._pump_task
+            if pump is not None and not pump.done():
+                # its tickets are resolved above; a worker round still
+                # running finishes on the pool, unobserved
+                stragglers.append(pump)
             for t in stragglers:
                 t.cancel()
             if stragglers:
                 await asyncio.gather(*stragglers, return_exceptions=True)
+            # last: on Python 3.12+ wait_closed() blocks while any client
+            # connection is open, so it must follow the cancels above
+            if self._server is not None:
+                await self._server.wait_closed()
             loop.stop()
 
         asyncio.run_coroutine_threadsafe(teardown(), loop)

@@ -2,7 +2,7 @@
 service core every wire shares, plus the in-process embedding mode for
 co-located frontends.
 
-PROFILE_r12's attribution made the split obvious: every correctness
+The split follows where the semantics live: every correctness
 semantic of the multi-frontend service — coalesced dispatch, bounded
 staleness + the Omega bind fence, the BindLedger's exactly-once, typed
 backpressure and deadline shedding — already lives BELOW the transport,
@@ -25,9 +25,8 @@ typed seam:
     fenced bind, conflict/overload retries with jittered backoff,
     idempotency-key replay of ambiguous attempts) as one call.
 
-The 100-frontend in-process fleet in bench.py measures this mode: on the
-2-core CI box it sustains 416-687 scheduleOnes/s — the number the binary
-wire is measured AGAINST (acceptance: within 2x over the wire).
+This mode is the zero-wire ceiling that the binary wire is held
+against: the same fleet over the wire should stay within 2x of it.
 """
 
 from __future__ import annotations

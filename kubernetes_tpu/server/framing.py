@@ -1,6 +1,6 @@
 """Compact length-prefixed binary framing for the fleet verbs (ISSUE 11).
 
-PROFILE_r12 attributed the fleet wall to the transport, not the payload:
+The fleet wall is the transport, not the payload:
 a NO-OP ThreadingHTTPServer measures ~196 req/s with 100 clients on the
 2-core box while the service answers a warm scheduleOne step in
 ~0.2-6 ms. This module is the wire half of killing that wall — a
@@ -43,9 +43,8 @@ Verbs — requests:
                 as the HTTP Content-Type switch). Response: SYNCED.
     METRICS     -> METRICS_TEXT (the Prometheus text the HTTP /metrics
                 serves).
-    PING        -> PONG, no service touch — the no-op round trip
-                bench.measure_wire_floor times against the threaded-HTTP
-                no-op floor.
+    PING        -> PONG, no service touch — the no-op round trip, the
+                wire's floor against the threaded-HTTP no-op floor.
     STATS       live introspection (ISSUE 13): u32 last_n ->
                 STATS_RESULT carrying the unified telemetry-registry
                 snapshot plus the flight recorder's last_n events as a

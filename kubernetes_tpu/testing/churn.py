@@ -22,7 +22,7 @@ replayable input so the streaming loop's robustness claims are MEASURED:
   seed-deterministic list of timed operations (node kills + respawns,
   NotReady flaps, cordon/uncordon, zone relabels, evictions, rolling
   updates). The SAME schedule object can drive a wall-clock thread
-  (bench.py's churn scenario) or be applied at step boundaries (the
+  (``ChurnInjector.run_thread``) or be applied at step boundaries (the
   frozen churn-trace A/B in tests) — determinism is the point: a churn
   bug reproduces from (seed, config), not from a lucky race.
 

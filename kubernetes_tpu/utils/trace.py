@@ -29,7 +29,7 @@ class SpanCounters:
     SpanCounters aggregate across thousands of fast ones (how many times
     did the extender rebuild AffinityData this session? where did the warm
     /filter's milliseconds go?). Tests assert on counts to pin cache
-    behavior structurally; profile_bench reads times for attribution."""
+    behavior structurally; the times serve attribution."""
 
     def __init__(self):
         self._lock = lockcheck.make_lock("SpanCounters._lock")
@@ -65,7 +65,7 @@ class SpanCounters:
 
 
 # process-wide registry, used by the extender fast lane (server/extender.py,
-# engine/scheduler_engine.evaluate_pod) and read by profile_bench + tests
+# engine/scheduler_engine.evaluate_pod) and read by the benchmark + tests
 COUNTERS = SpanCounters()
 
 
@@ -75,7 +75,7 @@ _annotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded
 def _load_annotation():
     """The profiler's annotation class, or None while JAX is not loaded:
     such a process has no profiler to record into, and a host-only
-    process (the trend CLI, the process-fleet parent) stays free of the
+    process (the process-fleet parent) stays free of the
     import."""
     global _annotation
     if "jax" in sys.modules:

@@ -134,15 +134,6 @@ def test_corrupt_checkpoint_dropped_on_restart(tmp_path):
     assert kubelet._restored == {}
 
 
-def test_bench_matrix_cell_runs_tiny():
-    """bench_matrix.py's cell runner end-to-end at toy scale (the
-    upstream bench matrix shape, scheduler_bench_test.go:32-52)."""
-    import bench_matrix
-
-    elapsed = bench_matrix.run_cell(20, 10, 30)
-    assert elapsed > 0
-
-
 def test_restore_all_survives_any_blob_shape(tmp_path):
     store = FileStore(str(tmp_path / "ckpt"))
     store.write("arr", b"[1, 2]")          # valid JSON, wrong shape

@@ -194,3 +194,47 @@ def test_tpu_backend_stale_node_labels_not_served_in_args_mode():
     n_changed = make_node("n2", labels={"zone": "c"})
     passed, failed = backend.filter(pod, [n_changed], None)
     assert passed == [] and "n2" in failed
+
+
+def test_compat_scheduleone_over_http_binds_everything():
+    """A stock scheduler's serial scheduleOne against the TPU backend over
+    real HTTP (core/extender.go Filter, Prioritize, Bind): /filter with
+    every node name (nodeCacheCapable), /prioritize over the survivors,
+    /bind on the top score. Every pod of a small stream binds."""
+    from kubernetes_tpu.models.hollow import hollow_nodes
+    from kubernetes_tpu.server.extender import TPUExtenderBackend
+
+    backend = TPUExtenderBackend()
+    nodes = hollow_nodes(200)
+    for i, n in enumerate(nodes):
+        n.labels["zone"] = f"z{i % 16}"
+    backend.sync_nodes(nodes)
+    names = [n.name for n in nodes]
+    srv = ExtenderHTTPServer(backend, prefix="/scheduler")
+    srv.start()
+    bound, unschedulable = [], []
+    try:
+        for i in range(60):
+            pod = make_pod(f"compat-{i}", cpu=100, memory=256 << 20)
+            enc = serde.encode_pod(pod)
+            status, out = post(srv.port, "/scheduler/filter",
+                               {"Pod": enc, "NodeNames": names})
+            assert status == 200 and not out.get("Error"), out
+            passed = out.get("NodeNames") or []
+            if not passed:
+                unschedulable.append(pod.name)
+                continue
+            status, scores = post(srv.port, "/scheduler/prioritize",
+                                  {"Pod": enc, "NodeNames": passed})
+            assert status == 200, scores
+            host = max(scores, key=lambda e: e["Score"])["Host"]
+            assert host in passed
+            status, out = post(srv.port, "/scheduler/bind",
+                               {"PodName": pod.name,
+                                "PodNamespace": pod.namespace,
+                                "PodUID": pod.uid, "Node": host})
+            assert status == 200 and not out.get("Error"), out
+            bound.append(pod.name)
+    finally:
+        srv.stop()
+    assert len(bound) == 60 and unschedulable == []

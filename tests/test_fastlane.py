@@ -437,65 +437,3 @@ def test_bulk_aging_guard_untouched_by_fast_tier():
     assert [p.name for p in popped] == ["old-victim", "young-vip"]
     assert [p.name for p in q.pop_fast()] == ["fast-0"]
     loop.close()
-
-
-# -------------------------------------------------------------- trend gate
-
-
-def _write_round(tmp_path, r, **metrics):
-    import json
-    doc = {"n": 1, "cmd": "python bench.py", "rc": 0, "parsed": metrics}
-    (tmp_path / f"BENCH_r{r:02d}.json").write_text(json.dumps(doc))
-
-
-def test_trend_learns_fastlane_headlines(tmp_path):
-    """bench --trend gates `fastlane_p99_ms` (down) and
-    `mixed_bulk_sustained` (up) from r19 on — absent history tolerated,
-    a past-band move in the bad direction flags."""
-    from kubernetes_tpu.observability import trend
-
-    assert ("fastlane_p99_ms", "fastlane p99 ms", "down") \
-        in trend.HEADLINE_METRICS
-    assert ("mixed_bulk_sustained", "mixed bulk frac", "up") \
-        in trend.HEADLINE_METRICS
-    _write_round(tmp_path, 18, value=30000.0)  # pre-r19: no fastlane keys
-    _write_round(tmp_path, 19, value=30000.0, fastlane_p99_ms=7.5,
-                 mixed_bulk_sustained=1.0)
-    assert trend.find_regressions(trend.load_rounds(str(tmp_path))) == []
-    _write_round(tmp_path, 20, value=30000.0, fastlane_p99_ms=25.0,
-                 mixed_bulk_sustained=0.5)  # both past the band, bad way
-    regs = trend.find_regressions(trend.load_rounds(str(tmp_path)))
-    assert sorted(g["metric"] for g in regs) == \
-        ["fastlane_p99_ms", "mixed_bulk_sustained"]
-
-
-def test_trend_annotates_box_shape_change(tmp_path, capsys):
-    """The r18 lesson as a feature: a flagged delta whose two rounds ran
-    on DIFFERENT cpu counts carries `box_change` and is reported but
-    NOT gated (exit 0); the same delta on a same-shape box gates."""
-    from kubernetes_tpu.observability import trend
-
-    _write_round(tmp_path, 18, churn_vs_quiet=0.85, cpus=2)
-    _write_round(tmp_path, 19, churn_vs_quiet=0.45, cpus=1)  # 2->1 core
-    regs = trend.find_regressions(trend.load_rounds(str(tmp_path)))
-    assert [g["metric"] for g in regs] == ["churn_vs_quiet"]
-    assert regs[0]["box_change"] == "2 -> 1 cpus"
-    assert trend.main(["--root", str(tmp_path)]) == 0
-    out = capsys.readouterr().out
-    assert "box change: 2 -> 1 cpus" in out and "not gated" in out
-    # same drop, same box shape: a real regression, exit 1
-    _write_round(tmp_path, 20, churn_vs_quiet=0.85, cpus=1)
-    _write_round(tmp_path, 21, churn_vs_quiet=0.45, cpus=1)
-    regs = trend.find_regressions(trend.load_rounds(str(tmp_path)))
-    assert regs and "box_change" not in regs[0]
-    assert trend.main(["--root", str(tmp_path)]) == 1
-
-
-def test_round_cpus_reads_r18_multiproc_fallback():
-    """Pre-r19 artifacts only disclosed the box inside the multiproc
-    sub-dict; `round_cpus` must still see it."""
-    from kubernetes_tpu.observability.trend import round_cpus
-
-    assert round_cpus({"cpus": 2}) == 2
-    assert round_cpus({"multiproc": {"cpus": 1}}) == 1
-    assert round_cpus({"value": 1.0}) is None

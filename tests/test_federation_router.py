@@ -18,9 +18,8 @@ Pins the four acceptance seams:
     field (the RELIST hydration path and the delta path can never
     disagree about a cell's capacity picture).
 
-Plus the satellite seams: brownout-schedule determinism, the A/B
-range-overlap escalation helper, and the trend reader's 1-core
-churn_vs_quiet annotation (non-gating, like box_change).
+Plus the satellite seams: brownout-schedule determinism and the
+unroutable backlog.
 """
 
 from __future__ import annotations
@@ -373,49 +372,6 @@ def test_brownout_schedule_deterministic_and_bounded():
         assert 1.0 <= op.t <= 9.0
         assert busy.get(op.cell, -1.0) < op.t, "same-cell overlap"
         busy[op.cell] = op.t + op.down_s
-
-
-def test_ab_ranges_overlap_helper():
-    from bench import _ab_ranges_overlap
-    assert _ab_ranges_overlap([1.0, 3.0], [2.5, 4.0])
-    assert not _ab_ranges_overlap([1.0, 2.0], [3.0, 4.0])
-    assert not _ab_ranges_overlap([], [1.0])
-    assert _ab_ranges_overlap([2.0], [2.0])
-
-
-def test_trend_single_core_churn_regression_not_gated():
-    """A churn_vs_quiet drop on a 1-cpu box against a round with no
-    recorded cpus is annotated single_core_band — reported, never
-    fatal (the r11-vs-r19 attribution: box shape, not code)."""
-    from kubernetes_tpu.observability.trend import find_regressions
-    rounds = [(11, {"churn_vs_quiet": 0.664}),
-              (21, {"churn_vs_quiet": 0.386, "cpus": 1,
-                    "churn_attribution": {"cpus": 1, "bar": 0.35}})]
-    regs = find_regressions(rounds)
-    assert len(regs) == 1
-    assert "single_core_band" in regs[0]
-    # WITHOUT the disclosed attribution the same drop still gates —
-    # leniency must be earned by evidence in the artifact
-    bare = find_regressions([(11, {"churn_vs_quiet": 0.664}),
-                             (21, {"churn_vs_quiet": 0.386, "cpus": 1})])
-    assert bare and "single_core_band" not in bare[0]
-    # the main() fatal filter drops annotated regressions
-    fatal = [g for g in regs
-             if "box_change" not in g and "single_core_band" not in g]
-    assert fatal == []
-    # a genuinely same-shape 2-core drop still gates
-    rounds2 = [(11, {"churn_vs_quiet": 0.664, "cpus": 2}),
-               (21, {"churn_vs_quiet": 0.386, "cpus": 2})]
-    regs2 = find_regressions(rounds2)
-    assert regs2 and "single_core_band" not in regs2[0] \
-        and "box_change" not in regs2[0]
-
-
-def test_trend_knows_federation_headlines():
-    from kubernetes_tpu.observability.trend import HEADLINE_METRICS
-    keys = {k for k, _l, _d in HEADLINE_METRICS}
-    assert {"federation_agg_nodes", "federation_router_p99_ms",
-            "federation_spillover_bound"} <= keys
 
 
 def test_unroutable_pods_backlog_then_admit_after_capacity():

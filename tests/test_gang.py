@@ -204,52 +204,67 @@ def test_gang_completing_in_timeout_round_schedules():
 
 
 def test_gang_pipelined_vs_classic_flush_ab():
-    """ISSUE 5 A/B: the same gang storm drained with gangs riding the
-    pipelined wave path (default) and in FLUSH mode (gang_pipeline=False
-    — every gang-bearing chunk drains the pipeline into the classic
-    synchronous round, the pre-ISSUE 5 routing). Placements may differ
-    (wave tie-breaks vs classic order) but the gang CONTRACT must agree:
-    the same gangs fully bind, zero partial gangs, zero residue for the
-    losers — and the pipelined run must actually dispatch gangs through
-    waves, never flushing."""
+    """ISSUE 5: a gang storm drained with gangs riding the pipelined wave
+    path (the classic flush-every-gang arm of this A/B is gone with the
+    routing it measured). The gang CONTRACT holds: the feasible gangs
+    fully bind, zero partial gangs, zero residue for the losers — and
+    the gangs really dispatch through waves."""
     from kubernetes_tpu.utils.trace import COUNTERS
 
-    def drain(gang_pipeline):
-        api = ApiServerLite()
-        for i in range(50):
-            api.create("Node", make_node(f"node-{i:03d}", cpu=16_000,
-                                         memory=64 * Gi))
-        for p in gang_pods(32 * 8):  # gangs 15 and 31 infeasible
-            api.create("Pod", p)
-        sched = Scheduler(api, record_events=False)
-        sched.gang_pipeline = gang_pipeline
-        sched.start()
-        COUNTERS.reset()
-        totals = sched.run_until_drained(max_batch=64)
-        snap = COUNTERS.snapshot()
-        by_gang = {}
-        for p in api.list("Pod")[0]:
-            by_gang.setdefault(p.annotations[GANG_NAME_ANNOTATION],
-                               []).append(bool(p.node_name))
-        return totals, by_gang, snap, sched
-
-    tot_p, gangs_p, snap_p, sched_p = drain(True)
-    tot_c, gangs_c, snap_c, _ = drain(False)
-    for by_gang in (gangs_p, gangs_c):
-        for gname, flags in by_gang.items():
-            assert len(set(flags)) == 1, f"{gname} partially bound"
-    bound_p = {g for g, f in gangs_p.items() if f[0]}
-    bound_c = {g for g, f in gangs_c.items() if f[0]}
-    assert bound_p == bound_c == {f"job-{g:04d}" for g in range(32)
-                                  if g % 16 != 15}
-    assert tot_p["bound"] == tot_c["bound"] == 30 * 8
-    # the pipelined run really took the wave path; flush mode never did
-    assert snap_p.get("engine.gang_wave_dispatch", (0, 0))[0] >= 30, snap_p
-    assert snap_c.get("engine.gang_wave_dispatch", (0, 0))[0] == 0, snap_c
+    api = ApiServerLite()
+    for i in range(50):
+        api.create("Node", make_node(f"node-{i:03d}", cpu=16_000,
+                                     memory=64 * Gi))
+    for p in gang_pods(32 * 8):  # gangs 15 and 31 infeasible
+        api.create("Pod", p)
+    sched = Scheduler(api, record_events=False)
+    sched.start()
+    COUNTERS.reset()
+    totals = sched.run_until_drained(max_batch=64)
+    snap = COUNTERS.snapshot()
+    by_gang = {}
+    for p in api.list("Pod")[0]:
+        by_gang.setdefault(p.annotations[GANG_NAME_ANNOTATION],
+                           []).append(bool(p.node_name))
+    for gname, flags in by_gang.items():
+        assert len(set(flags)) == 1, f"{gname} partially bound"
+    assert {g for g, f in by_gang.items() if f[0]} == \
+        {f"job-{g:04d}" for g in range(32) if g % 16 != 15}
+    assert totals["bound"] == 30 * 8
+    assert snap.get("engine.gang_wave_dispatch", (0, 0))[0] >= 30, snap
     # zero residue for the infeasible gangs: assumed capacity all released
     used = sum(i.requested.milli_cpu
-               for i in sched_p.cache.node_infos().values())
+               for i in sched.cache.node_infos().values())
     assert used == 30 * 8 * 100, used
+
+
+def test_gang_quorum_unreachable_on_waves_takes_classic_fallback():
+    """The one chunk-shape flush corner left: a gang whose quorum is
+    unreachable from its wave-eligible members (every member here carries
+    more host ports than a wave row encodes, so all ride as host-check
+    rows) would roll back on every re-dispatch, so its chunk flushes to
+    the classic round, which still places gangs all-or-nothing."""
+    from kubernetes_tpu.api.types import ContainerPort
+    from kubernetes_tpu.utils.trace import COUNTERS
+
+    def ports_member(name, gang, quorum):
+        p = _gang_pod(name, gang, quorum)
+        p.containers[0].ports = [ContainerPort(host_port=9000 + i)
+                                 for i in range(10)]
+        return p
+
+    api, sched = _rig(n_nodes=6, cpu=4000)
+    for i in range(4):
+        api.create("Pod", ports_member(f"fits-{i}", "fits", 4))
+    for i in range(8):  # the same host ports: one member per node at most
+        api.create("Pod", ports_member(f"wide-{i}", "wide", 8))
+    COUNTERS.reset()
+    sched.run_until_drained()
+    snap = COUNTERS.snapshot()
+    assert snap.get("engine.wave_flush_gang_host", (0, 0))[0] >= 1, snap
+    assert snap.get("stream.chunk_flush", (0, 0))[0] >= 1, snap
+    bound = {p.name for p in api.list("Pod")[0] if p.node_name}
+    assert bound == {f"fits-{i}" for i in range(4)}, sorted(bound)
 
 
 def test_gang_pipelined_overlap_ab_bit_identical():

@@ -418,7 +418,7 @@ def test_gl002_registry_does_not_taint_async_wire(tmp_path):
 
 
 def test_lint_gate_covers_new_wire_modules():
-    """ISSUE 11 satellite: `bench --lint-gate` discovers the new wire
+    """ISSUE 11 satellite: the lint gate discovers the new wire
     modules (they are ordinary package files — but a collection
     regression here would silently exempt the fleet transport from every
     rule, so the coverage is pinned)."""
@@ -1081,8 +1081,8 @@ def test_tree_lints_clean():
 
 
 def test_gate_is_pure_ast_fast():
-    """The gate must stay cheap enough for tier-1 and bench.py
-    --lint-gate: pure AST, no device — ~6 s on the idle 2-core CI box.
+    """The gate must stay cheap enough for tier-1: pure AST, no device
+    — ~6 s on the idle 2-core CI box.
     The bound is a regression guard against a rule going super-linear,
     not an SLO: 20 s leaves headroom for co-tenant contention (a
     contended full-suite run measured the same gate at 13 s) while any
@@ -1259,7 +1259,7 @@ def test_gl002_podtrace_slo_seams_stay_host_pure(tmp_path):
     to fill an event would serialize the pipeline at every sampled pod,
     the exact GL002 hazard at per-pod (not per-wave) cadence. The
     registry built over the REAL engine sources produces ZERO GL002
-    findings over podtrace/slo/trend; a trace consumer that fetches the
+    findings over podtrace/slo; a trace consumer that fetches the
     jitted packed result to stamp a timeline FIRES (the silence is the
     tracer's purity, not the rule going blind)."""
     import ast
@@ -1271,7 +1271,6 @@ def test_gl002_podtrace_slo_seams_stay_host_pure(tmp_path):
     obs_files = [
         os.path.join(PKG_DIR, "observability", "podtrace.py"),
         os.path.join(PKG_DIR, "observability", "slo.py"),
-        os.path.join(PKG_DIR, "observability", "trend.py"),
     ]
     # scan sanity: an empty jit registry would pass vacuously
     index = ProjectIndex()
@@ -1946,7 +1945,7 @@ def test_gl007_baseline_fingerprint_survives_line_drift(tmp_path):
 
 
 def test_lint_gate_refuses_concurrency_dirty_tree(tmp_path):
-    """`bench --lint-gate` refuses a tree carrying a torn counter or a
+    """The lint gate refuses a tree carrying a torn counter or a
     lock-order hazard the same way it refuses an aliasing upload."""
     (tmp_path / "bad.py").write_text(textwrap.dedent(GL007_TORN_COUNTER))
     ok, report = lint_gate(str(tmp_path))

@@ -32,10 +32,10 @@ from kubernetes_tpu.models.hollow import PROFILES, hollow_nodes, load_cluster
 from kubernetes_tpu.server.apiserver_lite import ApiServerLite
 from kubernetes_tpu.utils.trace import COUNTERS
 
-BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(
+BENCHMARK_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
-if BENCH_DIR not in sys.path:
-    sys.path.append(BENCH_DIR)
+if BENCHMARK_DIR not in sys.path:
+    sys.path.append(BENCHMARK_DIR)
 
 OWN = (777, 11, 13)      # the process's own thresholds in these tests
 
@@ -207,7 +207,7 @@ def test_idle_step_after_a_drained_wave_collects_once(own_collector):
 
 def _gc_reader():
     sp = importlib.util.spec_from_file_location(
-        "gc_ms_reader", os.path.join(BENCH_DIR, "metrics",
+        "gc_ms_reader", os.path.join(BENCHMARK_DIR, "metrics",
                                      "gc_ms_per_kpod.burst.py"))
     mod = importlib.util.module_from_spec(sp)
     sp.loader.exec_module(mod)

@@ -15,8 +15,7 @@ These tests pin the multi-process seams end to end:
     no pending asyncio task server-side — clean client closes drain to
     zero, and stop() cancels (and counts) any stragglers;
   - perfetto: one lane per scheduler process, fence-conflict events as
-    instant markers aligned to the ring time base;
-  - the trend gate learns the multiproc_N scenario headline from r18.
+    instant markers aligned to the ring time base.
 """
 
 from __future__ import annotations
@@ -393,30 +392,6 @@ def test_perfetto_renders_wire_conflicts_and_process_lanes():
     binds = [e for e in evs if e["ph"] == "X"
              and e.get("tid") == TID_PROC_BASE and e["name"] == "bind"]
     assert binds and binds[0]["dur"] == pytest.approx(1000.0, abs=0.2)
-
-
-# -------------------------------------------------------------- trend gate
-
-
-def test_trend_learns_multiproc_headline(tmp_path):
-    """bench --trend gates the multiproc_N aggregate from r18 on:
-    absent history tolerated, a past-band drop flags."""
-    from kubernetes_tpu.observability import trend
-
-    assert ("multiproc_pods_s", "multiproc agg/s", "up") \
-        in trend.HEADLINE_METRICS
-
-    def w(r, **metrics):
-        doc = {"n": 1, "cmd": "python bench.py", "rc": 0,
-               "parsed": metrics}
-        (tmp_path / f"BENCH_r{r:02d}.json").write_text(json.dumps(doc))
-
-    w(17, value=30000.0)  # pre-r18 round: no multiproc key
-    w(18, value=30000.0, multiproc_pods_s=50.0)
-    assert trend.find_regressions(trend.load_rounds(str(tmp_path))) == []
-    w(19, value=30000.0, multiproc_pods_s=20.0)  # -60%: regression
-    regs = trend.find_regressions(trend.load_rounds(str(tmp_path)))
-    assert [g["metric"] for g in regs] == ["multiproc_pods_s"]
 
 
 # ------------------------------------------- tsan-lite storm leg (ISSUE 19)

@@ -478,3 +478,20 @@ def test_churn_ops_land_on_the_ring(flight):
     assert len(ops) == sum(inj.applied.values())
     names = {rec.CHURN_OP_NAMES[e["a"]] for e in ops}
     assert names <= set(rec.CHURN_OP_CODES)
+
+
+def test_cli_records_a_drain_to_a_chrome_trace(tmp_path, capsys):
+    """`python -m kubernetes_tpu.observability`: --trace records a small
+    pipelined drain and writes a loadable trace-event JSON, leaving the
+    process-wide ring disarmed; with nothing to do it prints its usage
+    and exits 2."""
+    from kubernetes_tpu.observability.__main__ import main
+
+    assert main([]) == 2
+    assert "nothing to do" in capsys.readouterr().err
+    out = tmp_path / "trace.json"
+    assert main(["--trace", str(out), "--nodes", "16", "--pods", "64",
+                 "--no-warm"]) == 0
+    events = json.loads(out.read_text())["traceEvents"]
+    assert any(e.get("ph") == "X" for e in events)
+    assert not RECORDER.enabled

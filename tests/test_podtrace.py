@@ -1,6 +1,5 @@
 """Pod-level black box (ISSUE 15): sampled lifecycle tracing, typed
-fence reasons, SLO burn rates, trace-context transport parity, the
-trend reader.
+fence reasons, SLO burn rates, trace-context transport parity.
 
 Pins the contracts the tentpole rests on:
 
@@ -19,9 +18,7 @@ Pins the contracts the tentpole rests on:
   no duplicate BOUND events, every completed timeline matches a
   store-bound pod;
 - SLO burn-rate math: under-budget streams burn ~0, a sustained breach
-  alerts once (flip recorded on the flight-recorder ring) and recovers;
-- bench.py --trend flags a seeded synthetic regression with a nonzero
-  exit and stays quiet inside the noise band.
+  alerts once (flip recorded on the flight-recorder ring) and recovers.
 """
 
 from __future__ import annotations
@@ -35,7 +32,6 @@ from kubernetes_tpu.api.types import make_node, make_pod
 from kubernetes_tpu.engine.scheduler import Scheduler
 from kubernetes_tpu.models.hollow import PROFILES, hollow_nodes, load_cluster
 from kubernetes_tpu.observability import podtrace as pt
-from kubernetes_tpu.observability import trend
 from kubernetes_tpu.observability.podtrace import TRACER, PodTracer
 from kubernetes_tpu.observability.recorder import RECORDER
 from kubernetes_tpu.observability.slo import SLO, SLOMonitor
@@ -543,54 +539,6 @@ def test_perfetto_pod_lanes_render_exemplars(tracer):
     names = {e["name"] for e in pod_spans}
     assert names <= set(pt.PHASE_NAMES), names
     assert {"queue_wait", "in_flight"} <= names
-
-
-# ------------------------------------------------------------------ trend
-
-
-def _write_round(tmp_path, r, **metrics):
-    doc = {"n": 1, "cmd": "python bench.py", "rc": 0, "parsed": metrics}
-    (tmp_path / f"BENCH_r{r:02d}.json").write_text(json.dumps(doc))
-
-
-def test_trend_flags_seeded_regression_nonzero_exit(tmp_path, capsys):
-    _write_round(tmp_path, 1, value=30000.0,
-                 arrival_sustained_pods_s=20000.0,
-                 arrival_p99_create_to_bound_ms=120.0)
-    _write_round(tmp_path, 2, value=29000.0,
-                 arrival_sustained_pods_s=9000.0,   # -55%: regression
-                 arrival_p99_create_to_bound_ms=125.0)
-    rc = trend.main(["--root", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "arrival_sustained_pods_s" in out and "REGRESSIONS" in out
-
-
-def test_trend_quiet_inside_noise_band(tmp_path, capsys):
-    _write_round(tmp_path, 1, value=30000.0,
-                 arrival_p99_create_to_bound_ms=120.0)
-    _write_round(tmp_path, 2, value=24000.0,   # -20%: inside the band
-                 arrival_p99_create_to_bound_ms=140.0)
-    assert trend.main(["--root", str(tmp_path)]) == 0
-    assert "no regressions" in capsys.readouterr().out
-    # latency direction: an INCREASE past the band flags
-    _write_round(tmp_path, 3, value=30000.0,
-                 arrival_p99_create_to_bound_ms=250.0)
-    assert trend.main(["--root", str(tmp_path)]) == 1
-
-
-def test_trend_skips_missing_metrics_and_gaps(tmp_path):
-    _write_round(tmp_path, 1, value=30000.0,
-                 multi_frontend_pods_s=600.0)
-    _write_round(tmp_path, 2, value=29000.0)  # fleet metric absent
-    _write_round(tmp_path, 4, value=28000.0,  # gap + nearest-prev rule
-                 multi_frontend_pods_s=550.0)
-    assert trend.find_regressions(trend.load_rounds(str(tmp_path))) == []
-    _write_round(tmp_path, 5, value=27000.0,
-                 multi_frontend_pods_s=300.0)  # vs r04 550: -45%
-    regs = trend.find_regressions(trend.load_rounds(str(tmp_path)))
-    assert [g["metric"] for g in regs] == ["multi_frontend_pods_s"]
-    assert regs[0]["vs_round"] == 4
 
 
 # -------------------------------------------------------- registry fold

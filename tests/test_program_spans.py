@@ -37,10 +37,10 @@ from kubernetes_tpu.observability.recorder import RECORDER
 from kubernetes_tpu.server.apiserver_lite import ApiServerLite
 from kubernetes_tpu.utils.trace import COUNTERS, timed_span
 
-BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(
+BENCHMARK_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
-if BENCH_DIR not in sys.path:
-    sys.path.append(BENCH_DIR)
+if BENCHMARK_DIR not in sys.path:
+    sys.path.append(BENCHMARK_DIR)
 
 import program_trace  # noqa: E402
 from trace_reduce import DeviceTrace, find_xplane  # noqa: E402
